@@ -45,13 +45,14 @@ partial-decode machinery itself, which residency would mask.
 A fourth section measures the sharded fan-out (``repro.index.shard``,
 DESIGN.md §2.9) at shards ∈ {1, 2, 4} in a *device-compute-bound* regime
 (mid-size seeds, several long lists → large candidate-block partial
-decodes per row).  It runs in a subprocess under
+decodes per row).  It runs in-process over the devices the process has;
+CI launches the benchmark under
 ``--xla_force_host_platform_device_count=4`` so four host-platform devices
-exist on any machine while the parent — and every baseline above — stays
-single-device.  The ISSUE 4 gate is >1.5× batched throughput at 4 shards
-vs 1 in the full-size run (``sharded/speedup_s4`` in BENCH_engine.json);
-the smoke variant reports the same keys but is too small to gate on —
-scheduler-bound regimes measure the host, not the sharding.
+exist (the single-device sections above use the first).  The scaling
+gate is >1.5× batched throughput at 4 shards vs 1 in the full-size run
+(``sharded/speedup_s4`` in BENCH_engine.json); the smoke variant reports
+the same keys but is too small to gate on — scheduler-bound regimes
+measure the host, not the sharding.
 
 A fifth section (``dispatch/``, ISSUE 5) A/Bs megagroup fusion on the
 mixed-signature corpus: device dispatches per batch fused vs unfused
@@ -397,13 +398,17 @@ def _skewed(quick: bool) -> None:
         RESULTS["skewed/batched_skip_on_qps"] / max(dt, 1e-9), 2)
 
 
-def _sharded_worker(quick: bool) -> None:
-    """Child-process body for the sharded section: measures batched
-    uncached throughput at shards ∈ {1, 2, 4} and prints one JSON line.
-    Runs under --xla_force_host_platform_device_count=4 (set by the
-    parent) so 4 host-platform devices exist regardless of machine."""
-    import time
+def _sharded(quick: bool) -> None:
+    """Sharded fan-out scaling (gate: >1.5× batched throughput at
+    4 shards vs 1, uncached): batched uncached throughput at shards ∈
+    {1, 2, 4} over the devices this process has.  Runs in-process — a
+    child that needs the devices would find them held by this process.
+    CI sets ``XLA_FLAGS=--xla_force_host_platform_device_count=4`` before
+    launch so four host-platform devices exist; with fewer devices the
+    shards fold onto them (``shard.shard_index``)."""
     import jax
+    import numpy as np
+    from repro.index import batch as batch_lib
     from repro.index import builder, corpus as corpus_lib, engine, shard
 
     # device-compute-heavy regime (mid-size seed, two long lists → large
@@ -421,7 +426,7 @@ def _sharded_worker(quick: bool) -> None:
                         codec_name="bp-d1", B=0, n_parts=4)
     queries = corpus.queries
     seq = [engine.query(idx, q) for q in queries]
-    results = {"sharded/devices": len(jax.devices())}
+    RESULTS["sharded/devices"] = len(jax.devices())
     for n_shards in (1, 2, 4):
         sharded = shard.shard_index(idx, n_shards)
 
@@ -431,58 +436,23 @@ def _sharded_worker(quick: bool) -> None:
 
         out = run_once()
         for a, b in zip(out, seq):              # byte-identical gate
-            assert a.count == b.count
-            import numpy as np
-            assert np.array_equal(a.docs, b.docs)
+            assert a.count == b.count and np.array_equal(a.docs, b.docs)
         # warm to the signature fixed point before timing: arena growth
         # and residency staging settle over the first passes
-        from repro.index import batch as batch_lib
         batch_lib.warm_to_fixed_point(
             lambda s: shard.execute_sharded(sharded, queries, batch_size=32,
                                             depth=2, stats=s))
         qps = _qps(run_once, len(queries), reps=5)
-        results[f"sharded/batched_b32_s{n_shards}_qps"] = round(qps, 1)
-    results["sharded/speedup_s4"] = round(
-        results["sharded/batched_b32_s4_qps"]
-        / results["sharded/batched_b32_s1_qps"], 2)
-    print("SHARDED_JSON " + json.dumps(results))
-
-
-def _sharded(quick: bool) -> None:
-    """Sharded fan-out scaling (ISSUE 4 gate: >1.5× batched throughput at
-    4 shards vs 1, uncached, on host-platform devices).  Runs in a
-    subprocess with forced host device count so the parent process's
-    single-device state — and every baseline above — is undisturbed."""
-    import os
-    import subprocess
-    import sys
-
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(root, "src"), root]
-        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
-    cmd = [sys.executable, os.path.abspath(__file__), "--sharded-worker"]
-    if quick:
-        cmd.append("--smoke")
-    out = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                         timeout=1800)
-    if out.returncode != 0:
-        print(f"# sharded section FAILED: {out.stderr[-2000:]}")
-        raise SystemExit(2)
-    line = [ln for ln in out.stdout.splitlines()
-            if ln.startswith("SHARDED_JSON ")][-1]
-    results = json.loads(line[len("SHARDED_JSON "):])
-    RESULTS.update(results)
-    for n_shards in (1, 2, 4):
-        qps = results[f"sharded/batched_b32_s{n_shards}_qps"]
+        RESULTS[f"sharded/batched_b32_s{n_shards}_qps"] = round(qps, 1)
         emit(f"engine/sharded/batched_b32_s{n_shards}", 1.0 / qps,
              f"{qps:.1f} q/s "
-             f"{qps / results['sharded/batched_b32_s1_qps']:.2f}x")
+             f"{qps / RESULTS['sharded/batched_b32_s1_qps']:.2f}x")
+    RESULTS["sharded/speedup_s4"] = round(
+        RESULTS["sharded/batched_b32_s4_qps"]
+        / RESULTS["sharded/batched_b32_s1_qps"], 2)
     emit("engine/sharded/speedup_s4", 0.0,
-         f"{results['sharded/speedup_s4']:.2f}x on "
-         f"{results['sharded/devices']} host devices")
+         f"{RESULTS['sharded/speedup_s4']:.2f}x on "
+         f"{RESULTS['sharded/devices']} devices")
 
 
 def _latency(quick: bool) -> None:
@@ -910,12 +880,9 @@ def main() -> None:
                     help="print the per-batch schedule/assemble/dispatch/"
                          "device breakdown of the fused resident pipeline "
                          "and exit")
-    ap.add_argument("--sharded-worker", action="store_true",
-                    help=argparse.SUPPRESS)    # child of the sharded section
     args = ap.parse_args()
-    if args.sharded_worker:
-        _sharded_worker(args.smoke)
-        return
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.profile:
         _profile(args.smoke)
         return

@@ -92,9 +92,9 @@ program and returns a ``PendingBatch`` of un-materialized device results;
 ``collect_batch`` blocks and aggregates) so ``repro.index.pipeline`` can
 overlap host scheduling of batch k+1 with device execution of batch k.
 ``execute_batch`` composes the two and is byte-identical to the sequential
-engine.  On non-CPU backends the candidate buffer is donated to the device
-program — it is freshly assembled per dispatch and never reused, so XLA can
-reuse its pages for the output.
+engine.  The candidate buffer is donated to the device program — it is
+freshly assembled per dispatch and never reused, so XLA can reuse its
+pages for the output.
 
 Algorithm choice: under ``vmap`` the tiled merge runs lock-step across the
 batch — the slowest row sets the step count and its data-dependent early
@@ -146,8 +146,7 @@ PALLAS_MIN_OCCUPANCY = 0.5    # interpret-mode kernel guard; see below
 
 # Donating the candidate buffer lets XLA alias its pages for the output; it
 # is always freshly stacked per dispatch so nothing aliases it on the host.
-# CPU has no donation support (XLA warns and ignores), so gate it.
-_DONATE_CANDIDATES = (0,) if jax.default_backend() != "cpu" else ()
+_DONATE_CANDIDATES = (0,)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -400,10 +399,26 @@ def _mask_fold_scan(r, valid, folds, fold_active, intersect_fn):
     return valid
 
 
-@partial(jax.jit, static_argnames=("algo", "backend", "mode", "block_rows"),
+def _row_split(fn, mesh, *fold_args):
+    """Run a megakernel per device of ``mesh`` over its slice of the batch
+    rows.  XLA cannot partition a Mosaic kernel, and group programs are
+    row-independent, so each device runs the kernel on the rows it holds:
+    ``r``/``valid`` split on axis 0, fold-stacked operands on axis 1."""
+    if mesh is None:
+        return fn
+    from jax.sharding import PartitionSpec as P
+    row, fold = P("data"), P(None, "data")
+    return jax.shard_map(
+        fn, mesh=mesh, out_specs=row, check_vma=False,
+        in_specs=(row, row) + tuple(
+            jax.tree.map(lambda _: fold, a) for a in fold_args))
+
+
+@partial(jax.jit, static_argnames=("algo", "backend", "mode", "block_rows",
+                                   "mesh"),
          donate_argnums=_DONATE_CANDIDATES)
 def _svs_program(r, folds, fold_active, pk, pk_active, words, algo: str,
-                 backend: str, mode: str, block_rows: int):
+                 backend: str, mode: str, block_rows: int, mesh=None):
     """One device program per group chunk: decoded folds → packed folds →
     bitmap probes, candidates staying on device throughout.  Every stage
     computes a match mask over the original sorted seed buffer ``r`` and
@@ -411,16 +426,19 @@ def _svs_program(r, folds, fold_active, pk, pk_active, words, algo: str,
     invalid slots set to SENTINEL — per-row sorted but NOT compacted (the
     host extracts the valid prefix-by-mask at collect).  ``pk`` is the
     tuple of stacked batch-uniform packed operands (or None); ``words`` the
-    stacked bitmap rows (or None).  ``r`` is donated off-CPU (see module
-    docstring)."""
+    stacked bitmap rows (or None).  ``r`` is donated (see module
+    docstring).  ``mesh`` is the sharded executor's ('data',) mesh when
+    the batch rows are split across devices (None on one device): the
+    pallas megakernels then run per device on its rows."""
     valid = r != its.SENTINEL
     if folds.shape[0]:
         if backend == "pallas":
             # fused megakernel: the whole J-fold stack in one launch
             # (grid (B, J), mask accumulated in the revisited out block)
             from repro.kernels import ops as kernel_ops
-            valid = kernel_ops.intersect_fold_batch(r, valid, folds,
-                                                    fold_active)
+            valid = _row_split(kernel_ops.intersect_fold_batch, mesh,
+                               folds, fold_active)(r, valid, folds,
+                                                   fold_active)
         else:
             if algo == "tiled":
                 fold_fn = partial(its.intersect_tiled_batch,
@@ -435,8 +453,10 @@ def _svs_program(r, folds, fold_active, pk, pk_active, words, algo: str,
             # kernel scratch, gallop, fold — one launch for the Jp stack,
             # no materialized decoded array (DESIGN.md §2.12)
             from repro.kernels import ops as kernel_ops
-            valid = kernel_ops.intersect_packed_fold(
-                r, valid, pk, pk_active, mode=mode, block_rows=block_rows)
+            valid = _row_split(
+                partial(kernel_ops.intersect_packed_fold, mode=mode,
+                        block_rows=block_rows),
+                mesh, pk, pk_active)(r, valid, pk, pk_active)
         else:
             valid = _mask_fold_scan(
                 r, valid, pk, pk_active,
@@ -716,12 +736,39 @@ def _effective_backend(key: GroupKey, items: list[_Item], backend: str,
     if backend != "pallas":
         return backend
     from repro.kernels import ops as kernel_ops
-    if not kernel_ops.INTERPRET:
+    if kernel_ops.kernel_mode() == "compiled":
         return backend
     if pallas_occupancy(key, items, bp) < PALLAS_MIN_OCCUPANCY:
         source._bump(stats, "pallas_lowocc_fallbacks")
         return "jax"
     return backend
+
+
+def count_folds(stats: dict | None, key: GroupKey, items: list,
+                backend: str):
+    """Count the real folds one svs launch runs, and where: ``folds`` all
+    of them; under the pallas backend ``kernel_folds`` those a megakernel
+    runs and ``kernel_vmem_fallbacks`` those whose shapes do not fit the
+    kernels' VMEM limit and so run the jnp reference (the same
+    ``kernels.ops`` predicates the program routes on)."""
+    if stats is None:
+        return
+    items = [it for it in items if it is not None]
+    dec = sum(len(it.folds or ()) for it in items)
+    pk = sum(len(it.psrc or ()) for it in items)
+    source._bump(stats, "folds", dec + pk)
+    if backend != "pallas":
+        return
+    from repro.kernels import ops as kernel_ops
+    kernel = dec if kernel_ops.decoded_fold_fits(key.m_bucket,
+                                                 key.n_bucket) else 0
+    if pk:
+        k_pad, t_pad, c_pad, e_pad, rows, _ = key.packed
+        if kernel_ops.packed_fold_fits(key.m_bucket, t_pad, k_pad, c_pad,
+                                       e_pad, rows):
+            kernel += pk
+    source._bump(stats, "kernel_folds", kernel)
+    source._bump(stats, "kernel_vmem_fallbacks", dec + pk - kernel)
 
 
 def _launch_svs_group(key: GroupKey, items: list[_Item], backend: str,
@@ -733,6 +780,7 @@ def _launch_svs_group(key: GroupKey, items: list[_Item], backend: str,
     ``pipeline.StageTimings``) splits operand assembly from the async
     program enqueue."""
     backend = _effective_backend(key, items, backend, stats)
+    count_folds(stats, key, items, backend)
     t0 = time.perf_counter()
     R, F, active, pkparts, W, Bp, J, Jb = _assemble_svs(key, items, pool)
     pk = pk_active = None
@@ -1012,19 +1060,12 @@ def _compile_count() -> int:
     """Total jit-cache entries behind the group programs, the arena
     gather, and every row stacker (the arena-fallback path compiles stack
     programs mid-serving, e.g. when a cache fill drops a row's host copy)
-    — the compiles ``warmup`` is meant to front-load.  Uses jax's
-    (private, guarded) ``_cache_size``; returns 0 when the running jax
-    does not expose it, which only disables the ``n_compiles``
-    *reporting*, never correctness."""
-    n = 0
-    for fn in (_svs_program, _bitmap_and_program, _GATHER, *_STACKERS):
-        size = getattr(fn, "_cache_size", None)
-        if size is not None:
-            try:
-                n += size()
-            except Exception:
-                pass
-    return n
+    — the compiles ``warmup`` is meant to front-load.  Reads jax's jit
+    ``_cache_size``, which the installed jax provides; a jax without it
+    fails here rather than reporting 0 compiles."""
+    return sum(fn._cache_size()
+               for fn in (_svs_program, _bitmap_and_program, _GATHER,
+                          *_STACKERS))
 
 
 # --------------------------------------------------------------------------

@@ -317,7 +317,7 @@ def build_sharded(postings: list[np.ndarray], n_docs: int, *, n_shards: int,
                   codec_name: str = "bp-d1", B: int = 0,
                   n_parts: int | None = None, keep_raw: bool = False,
                   varint_tail_below: int = 1024,
-                  capacity_ints: int = 1 << 26, warm: bool = True,
+                  capacity_ints: int | None = None, warm: bool = True,
                   cost_table=None):
     """Per-part build placed onto data-parallel shards (DESIGN.md §2.5).
 

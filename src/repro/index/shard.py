@@ -25,11 +25,10 @@ Execution model — shard along the batch axis, not the program:
   sharded == sequential a structural identity rather than a numerical
   accident (``tests/test_shard.py`` locks it in).
 
-  AxisType constraint: meshes here are plain ``Mesh`` objects —
-  ``jax.sharding.AxisType`` does not exist on the pinned jax 0.4.37, and
-  nothing in this dataflow needs it (every axis is Auto).  The whole layer
-  runs under ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` for
-  tests/CI and on real device fleets unchanged.
+  Meshes here are plain ``Mesh`` objects: nothing in this dataflow needs
+  explicit axis types (every axis is Auto).  The whole layer runs under
+  ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` for tests/CI and
+  on real device fleets unchanged.
 
   More shards than devices is allowed (shards fold onto devices
   contiguously, ``n_shards %% n_devices == 0``), which keeps the shard
@@ -72,7 +71,7 @@ class ShardedIndex:
     placement, and one device-pinned ResidentPool per shard."""
     index: HybridIndex
     n_shards: int
-    mesh: object                      # 1-D ('data',) Mesh, AxisType-free
+    mesh: object                      # 1-D ('data',) Mesh
     part_shard: list                  # part ordinal -> shard id (contiguous)
     placement: list                   # shard id -> jax Device
     pools: list                       # shard id -> source.ResidentPool
@@ -115,7 +114,7 @@ class ShardedIndex:
 
 
 def shard_index(index: HybridIndex, n_shards: int, devices=None,
-                capacity_ints: int = 1 << 26, warm: bool = True
+                capacity_ints: int | None = None, warm: bool = True
                 ) -> ShardedIndex:
     """Place an index's parts onto ``n_shards`` data-parallel shards.
 
@@ -258,8 +257,10 @@ def _launch_svs_sharded(sharded: ShardedIndex, key, per_shard: list,
     # the sharded grid's S·Bq batch rows
     backend = batch_lib._effective_backend(key, all_items, backend, stats,
                                            bp=S * Bq)
+    batch_lib.count_folds(stats, key, all_items, backend)
     vals, counts = batch_lib._svs_program(
-        R, F, active, pk, pk_active, W, key.algo, backend, mode, rows)
+        R, F, active, pk, pk_active, W, key.algo, backend, mode, rows,
+        mesh=sharded.mesh if len(sharded.devices) > 1 else None)
     return _flat_items(per_shard, Bq), vals, counts
 
 

@@ -342,6 +342,17 @@ class RowArena:
             self._buf = jax.device_put(np.stack(rows), self.device)
         return self._buf
 
+
+def device_capacity_ints(device=None) -> int:
+    """Default pool budget: half of the device's memory, in int32s, as
+    the backend reports it (``bytes_limit``); 2^26 ints where it reports
+    none (the CPU backend).  A budget below the working set makes the LRU
+    evict and re-decode rows every batch."""
+    dev = device if device is not None else jax.devices()[0]
+    limit = (dev.memory_stats() or {}).get("bytes_limit")
+    return limit // 8 if limit else 1 << 26
+
+
 class ResidentPool:
     """Device-resident index operands: decoded value rows and bitmap word
     rows staged once with explicit ``jax.device_put`` and reused by every
@@ -381,8 +392,10 @@ class ResidentPool:
     (single-device serving, unchanged).
     """
 
-    def __init__(self, capacity_ints: int = 1 << 26, device=None, tag=None):
-        self.capacity = capacity_ints
+    def __init__(self, capacity_ints: int | None = None, device=None,
+                 tag=None):
+        self.capacity = (device_capacity_ints(device) if capacity_ints is None
+                         else capacity_ints)
         self.device = device
         self.tag = tag                 # generation tag (DESIGN.md §2.14)
         self._store: OrderedDict = OrderedDict()

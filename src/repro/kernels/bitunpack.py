@@ -9,7 +9,13 @@ The two-pass ("-NI") comparison point materializes deltas first (see ops.py).
 
 Working set per grid step: 32·128·4 B in + 32·128·4 B out = 32 KiB ≪ VMEM.
 Bit widths and seeds ride in scalar-prefetch (SMEM), mirroring the paper's
-per-block metadata bytes.
+per-block metadata bytes.  Packed rows are read straight from the input
+ref at their dynamic word index (``ref[pl.ds(w, 1), :]``) and lane scans
+are shift-adds over ``pltpu.roll`` — the forms Mosaic lowers.
+
+``unpack_row`` / ``prefix_rows`` / ``decode_candidates`` are the in-kernel
+stages the packed megakernel (``megakernel.py``) reuses to decode a row's
+candidate blocks into VMEM scratch.
 
 Validated against ``ref.unpack_blocks_ref`` (pure jnp) in interpret mode for
 every bit width b ∈ [0, 32] × every delta mode (tests/test_kernels.py).
@@ -19,35 +25,166 @@ from __future__ import annotations
 
 from functools import partial
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import bitpack as core_bitpack
 from repro.core import deltas as core_deltas
 
 ROWS = 32
 LANES = 128
+SENTINEL = np.int32(2**31 - 1)
 
 
-def _lane_cumsum(row):
-    """Inclusive cumsum over 128 lanes via log2(128)=7 shift-adds
-    (Hillis–Steele; TPU-friendly: pad+add, no scatter)."""
-    x = row
-    for k in (1, 2, 4, 8, 16, 32, 64):
-        x = x + jnp.pad(x, (k, 0))[:LANES]
+def _lane():
+    return lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+
+
+def lane_scan(x, s: int = 1):
+    """Inclusive stride-``s`` scan over the 128 lanes of a (1, 128) int32
+    row: lane l sums x[l], x[l-s], x[l-2s], … (Hillis–Steele shift-adds)."""
+    lane = _lane()
+    k = s
+    while k < LANES:
+        x = x + jnp.where(lane >= k, pltpu.roll(x, k, 1), 0)
+        k *= 2
     return x
 
 
-def _stride_cumsum(row, s: int, carry):
-    """Per-row stride-s chain cumsum. carry: (s,) running value per phase.
-    Returns (new_row, new_carry)."""
-    C = LANES // s
-    m = row.reshape(C, s)
-    pc = jnp.cumsum(m, axis=0, dtype=jnp.uint32)
-    out = pc + carry[None, :]
-    return out.reshape(LANES), out.reshape(LANES // s, s)[-1]
+def _phase_tail(row, s: int):
+    """Per-lane carry of a stride-``s`` chain: lane l gets the last value
+    of its phase in ``row`` (row[128 − s + l mod s])."""
+    if s == 1:
+        return jnp.broadcast_to(row[:, LANES - 1:], (1, LANES))
+    return lane_scan(jnp.where(_lane() < s, pltpu.roll(row, s, 1), 0), s)
+
+
+def prefix_rows(ref, base, n_rows: int, seed, mode: str):
+    """In-place prefix sum P of rows [base, base + n_rows) of an int32
+    (·, 128) ref holding one block's deltas, seeded with the scalar
+    ``seed`` (int32 arithmetic wraps like the uint32 reference)."""
+    if mode == "none":
+        return
+    carry = jnp.full((1, LANES), seed, jnp.int32)
+    for r in range(n_rows):
+        d = ref[pl.ds(base + r, 1), :]
+        if mode == "dv":
+            row = d + carry
+            carry = row
+        elif mode == "dm":
+            row = d + carry
+            carry = _phase_tail(row, 1)
+        else:
+            s = core_deltas._STRIDE[mode]
+            row = lane_scan(d, s) + carry
+            carry = _phase_tail(row, s)
+        ref[pl.ds(base + r, 1), :] = row
+
+
+def unpack_row(w_ref, off, width, r: int):
+    """Row ``r`` of the block of bit width ``width`` whose packed words
+    start at row ``off`` of the (T, 128) uint32 ref — returned as int32."""
+    t_max = w_ref.shape[0] - 1
+    start = r * width
+    w = start >> 5
+    sh = (start & 31).astype(jnp.uint32)
+    bu = width.astype(jnp.uint32)
+    lo = w_ref[pl.ds(jnp.minimum(off + w, t_max), 1), :]
+    hi = w_ref[pl.ds(jnp.minimum(off + w + 1, t_max), 1), :]
+    mask = jnp.where(width >= 32, jnp.uint32(0xFFFFFFFF),
+                     (jnp.uint32(1) << jnp.minimum(bu, 31)) - 1)
+    t = lo >> sh
+    t = jnp.where(sh + bu > 32, t | (hi << ((jnp.uint32(32) - sh) & 31)), t)
+    return lax.bitcast_convert_type(t & mask, jnp.int32)
+
+
+def scalar_at(ref, i):
+    """Element ``i`` of a flat int32 vector laid out as a (·, 128) ref.
+    Mosaic reads a scalar from VMEM only at a static lane, so the row is
+    loaded and the lane selected by a masked reduction."""
+    row = ref[pl.ds(i // LANES, 1), :]
+    return jnp.sum(jnp.where(_lane() == i % LANES, row, 0))
+
+
+def _patch_exceptions(ep_ref, ea_ref, win_ref, e, bid, base, per: int,
+                      n_exc: int):
+    """Add the FastPFOR patches of block ``bid`` to its deltas at window
+    rows [base, base + per/128).  Exceptions are sorted by position (-1
+    padded at the tail) and candidate blocks ascend, so the pointer ``e``
+    only moves forward across a row's blocks."""
+    lo_pos = bid * per
+
+    def pos(e):
+        return scalar_at(ep_ref, jnp.minimum(e, n_exc - 1))
+
+    def ahead(e):
+        p = pos(e)
+        return (e < n_exc) & (p >= 0) & (p < lo_pos)
+
+    e = lax.while_loop(ahead, lambda e: e + 1, e)
+
+    def inside(e):
+        p = pos(e)
+        return (e < n_exc) & (p >= 0) & (p < lo_pos + per)
+
+    def patch(e):
+        p = pos(e) - lo_pos
+        row = base + p // LANES
+        add = scalar_at(ea_ref, e)
+        cur = win_ref[pl.ds(row, 1), :]
+        win_ref[pl.ds(row, 1), :] = cur + jnp.where(_lane() == p % LANES,
+                                                    add, 0)
+        return e + 1
+
+    return lax.while_loop(inside, patch, e)
+
+
+def decode_candidates(w_ref, wid_ref, off_ref, max_ref, blk_ref, ep_ref,
+                      ea_ref, win_ref, *, mode: str, block_rows: int,
+                      k_pad: int, n_cand: int, n_exc: int):
+    """In-kernel partial decode of one row's candidate blocks into the
+    (n_cand·block_rows, 128) int32 scratch ``win_ref`` — a sorted window,
+    SENTINEL on pad slots.
+
+    This is the scratch-decode stage of the packed megakernel: read each
+    candidate block's width/offset, bit-unpack its deltas with the same
+    shift/mask rows as the Algorithm-1 kernel above, patch FastPFOR
+    exceptions whose block made the candidate list, and prefix-sum with
+    the per-block seed (the previous block's max).  Metadata refs are flat
+    int32 vectors laid out (·, 128).  Block ids ≥ ``k_pad`` are pad
+    candidates: their rows are SENTINEL, so the window stays sorted and the
+    probe can never match a pad slot (DESIGN.md §2.6, §2.12)."""
+    per = block_rows * LANES
+
+    def block(c, e):
+        bid = scalar_at(blk_ref, c)
+        base = pl.multiple_of(c * block_rows, 8)
+
+        def real(e):
+            width = scalar_at(wid_ref, bid)
+            off = scalar_at(off_ref, bid)
+            seed = jnp.where(bid > 0,
+                             scalar_at(max_ref, jnp.maximum(bid - 1, 0)), 0)
+            for r in range(block_rows):
+                win_ref[pl.ds(base + r, 1), :] = unpack_row(w_ref, off,
+                                                            width, r)
+            if n_exc:
+                e = _patch_exceptions(ep_ref, ea_ref, win_ref, e, bid, base,
+                                      per, n_exc)
+            prefix_rows(win_ref, base, block_rows, seed, mode)
+            return e
+
+        def pad(e):
+            win_ref[pl.ds(base, block_rows), :] = jnp.full(
+                (block_rows, LANES), SENTINEL, jnp.int32)
+            return e
+
+        return lax.cond(bid < k_pad, real, pad, e)
+
+    lax.fori_loop(0, n_cand, block, jnp.int32(0))
 
 
 def make_unpack_kernel(mode: str):
@@ -55,93 +192,13 @@ def make_unpack_kernel(mode: str):
 
     def kernel(widths_ref, seeds_ref, words_ref, out_ref):
         k = pl.program_id(0)
-        b = widths_ref[k].astype(jnp.uint32)
-        seed = seeds_ref[k]
-        words = words_ref[0]                       # (32, 128) uint32
-        mask = jnp.where(b >= 32, jnp.uint32(0xFFFFFFFF),
-                         (jnp.uint32(1) << jnp.minimum(b, 31)) - 1)
-
-        # prefix-sum state v (paper: "seed vector v")
-        if mode == "dv":
-            carry = jnp.full((LANES,), seed, dtype=jnp.uint32)
-        elif mode in ("d2", "d4"):
-            s = {"d2": 2, "d4": 4}[mode]
-            carry = jnp.full((s,), seed, dtype=jnp.uint32)
-        else:                                      # d1 / dm / none: scalar
-            carry = seed
-
-        out = jnp.zeros((ROWS, LANES), dtype=jnp.uint32)
+        width = widths_ref[k]
         for r in range(ROWS):                      # static unroll, as in the
-            start = jnp.uint32(r) * b              # paper's generated code
-            w = (start >> 5).astype(jnp.int32)
-            sh = start & 31
-            lo = lax.dynamic_index_in_dim(words, w, axis=0, keepdims=False)
-            hi = lax.dynamic_index_in_dim(
-                words, jnp.minimum(w + 1, ROWS - 1), axis=0, keepdims=False)
-            spill = (sh + b) > 32
-            t = lo >> sh
-            t = jnp.where(spill, t | (hi << ((jnp.uint32(32) - sh) & 31)), t)
-            t = t & mask                           # single reusable mask (§4)
-
-            # t ← P(t, v);  v ← t
-            if mode == "none":
-                row = t
-            elif mode == "dv":
-                row = t + carry
-                carry = row
-            elif mode == "dm":
-                row = t + carry
-                carry = row[LANES - 1]
-            elif mode == "d1":
-                row = _lane_cumsum(t) + carry
-                carry = row[LANES - 1]
-            else:                                  # d2 / d4
-                row, carry = _stride_cumsum(t, carry.shape[0], carry)
-            out = lax.dynamic_update_index_in_dim(out, row, r, axis=0)
-        out_ref[0] = out
+            out_ref[pl.ds(r, 1), :] = unpack_row(  # paper's generated code
+                words_ref, 0, width, r)
+        prefix_rows(out_ref, 0, ROWS, seeds_ref[k], mode)
 
     return kernel
-
-
-def decode_candidates(words, widths, offsets, maxes, blk, exc_pos, exc_add,
-                      *, mode: str, block_rows: int):
-    """In-kernel partial decode of one row's candidate blocks → a flat
-    sorted int32 window, SENTINEL-filled on pad slots.
-
-    This is the scratch-decode stage shared by the per-fold packed-gallop
-    kernel (``intersect_gallop.make_packed_gallop_kernel``) and the fused
-    megakernel (``megakernel.make_packed_fold_kernel``): gather each
-    candidate block's width/offset, bit-unpack its deltas with the same
-    shift/mask machinery as the Algorithm-1 kernel above (vectorized via
-    ``core.bitpack.unpack_deltas``), patch FastPFOR exceptions whose block
-    made the candidate list, and prefix-sum with the per-block seed.  All
-    operands are this row's VMEM-resident refs read inside a Pallas kernel
-    body; every shape is static so the whole stage traces into the kernel.
-
-    ``blk`` entries ≥ K_pad are pad candidates: they decode block K_pad−1
-    (harmlessly) and their ``per`` output lanes are overwritten with
-    SENTINEL, so the window stays sorted and the gallop probe can never
-    match a pad slot (DESIGN.md §2.6, §2.12)."""
-    from repro.core.intersect import SENTINEL
-    per = block_rows * LANES
-    Kp = maxes.shape[0]
-    C = blk.shape[0]
-    pad = blk >= Kp
-    ids = jnp.minimum(blk, Kp - 1)
-    seeds = jnp.where(ids > 0,
-                      jnp.take(maxes, jnp.maximum(ids - 1, 0)),
-                      jnp.uint32(0))
-    d = core_bitpack.unpack_deltas(words, jnp.take(widths, ids),
-                                   jnp.take(offsets, ids), block_rows)
-    if exc_pos is not None:
-        eb = exc_pos // per
-        slot = jnp.clip(jnp.searchsorted(blk, eb), 0, C - 1)
-        ok = (exc_pos >= 0) & (jnp.take(blk, slot) == eb)
-        tgt = jnp.where(ok, slot * per + exc_pos % per, C * per)
-        d = d.reshape(-1).at[tgt].add(exc_add, mode="drop").reshape(d.shape)
-    vals = core_deltas.prefix_sum(d, seeds, mode)
-    flat = vals.reshape(-1).astype(jnp.int32)         # (C·per,) sorted
-    return jnp.where(jnp.repeat(pad, per), SENTINEL, flat)
 
 
 @partial(jax.jit, static_argnames=("mode", "interpret"))
@@ -149,20 +206,23 @@ def unpack_blocks(padded_words, widths, seeds, mode: str = "d1",
                   interpret: bool = True):
     """padded_words: (K, 32, 128) uint32 (block-padded packed words);
     widths, seeds: (K,).  Returns (K, 32, 128) uint32 decoded values."""
-    from jax.experimental.pallas import tpu as pltpu
+    from repro.kernels.megakernel import compiler_params
 
     K = padded_words.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                 # widths, seeds → SMEM
         grid=(K,),
-        in_specs=[pl.BlockSpec((1, ROWS, LANES), lambda k, *_: (k, 0, 0))],
-        out_specs=pl.BlockSpec((1, ROWS, LANES), lambda k, *_: (k, 0, 0)),
+        in_specs=[pl.BlockSpec((None, ROWS, LANES), lambda k, *_: (k, 0, 0))],
+        out_specs=pl.BlockSpec((None, ROWS, LANES), lambda k, *_: (k, 0, 0)),
     )
-    kernel = make_unpack_kernel(mode)
-    return pl.pallas_call(
-        kernel,
+    out = pl.pallas_call(
+        make_unpack_kernel(mode),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((K, ROWS, LANES), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((K, ROWS, LANES), jnp.int32),
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(widths.astype(jnp.int32), seeds.astype(jnp.uint32),
+        name="unpack_blocks",
+    )(widths.astype(jnp.int32),
+      lax.bitcast_convert_type(seeds.astype(jnp.uint32), jnp.int32),
       padded_words.astype(jnp.uint32))
+    return lax.bitcast_convert_type(out, jnp.uint32)

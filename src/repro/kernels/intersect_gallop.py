@@ -1,177 +1,158 @@
-"""Pallas TPU kernel: SIMD Galloping intersection (paper §5, Algorithm 4).
+"""Pallas TPU intersection probe: SIMD Galloping (paper §5, Algorithm 4).
 
-TPU adaptation (DESIGN.md §2.4): the paper gallops serially per element of the
-short list; here one grid step takes a 128-lane tile of the short list ``r``
-and runs **128 binary searches in parallel** against the long list ``f`` held
-in VMEM — log2(N) rounds of branchless lower-bound probing (vector gathers),
-then one gather + compare for the membership test.  Same O(m/τ · log n)
-complexity as Algorithm 4 at τ = 128 with the doubling phase replaced by
-full binary search (depth-optimal on vectors; sequential doubling has no TPU
-advantage).
+The paper gallops a scalar over the long list ``f`` to the block that may
+hold each element of the short list ``r``, then compares the element
+against that whole block in one SIMD instruction.  Mosaic has no vector
+gather, so the TPU form keeps the same two steps at tile granularity
+(DESIGN.md §2.4):
 
-VMEM budget: f must fit in VMEM (N ≤ 2**20 → 4 MiB).  Longer lists go through
-``ops.intersect_gallop`` which first searches the block-max skip index (this
-kernel again) and then probes candidate blocks.
+* **gallop** — for each 128-lane tile of ``r`` (sorted), scalar reads of
+  the block maxima of ``f`` (one block = one (8, 128) vreg tile of sorted
+  values) gallop from the previous tile's block to the first block that
+  can hold the tile's smallest live value: exponential steps, then a
+  binary narrowing;
+* **block comparison** — every block whose first value is ≤ the tile's
+  largest live value is compared against all 128 ``r`` lanes at once: the
+  tile is transposed into a (128, 128) column broadcast and each of the
+  block's 8 rows is one equality test per vreg.  A lane is a hit when any
+  compared ``f`` value equals it.
+
+Work per tile is the blocks its value range overlaps, and the block
+pointer only moves forward, so a whole row costs O(M/128 + N/1024) block
+comparisons plus O(log) scalar probes per tile — merge-like for dense
+pairs, galloping for skewed ones.  Lanes whose running validity is
+already 0 do not widen the window, so later folds of an SvS chain get
+cheaper as candidates die.
+
+``probe_tiles`` is the in-kernel routine shared by both megakernels
+(``megakernel.py``); ``gallop_tiles`` / ``gallop_tiles_batched`` /
+``packed_gallop_batched`` are the per-fold entry points, each a one-fold
+launch of the corresponding megakernel.
 """
 
 from __future__ import annotations
 
-from functools import partial
-
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 
 from repro.core import bitpack as core_bitpack
-from repro.kernels import bitunpack as _bitunpack
 
-TILE_R = 128
+LANES = core_bitpack.LANES
 SENTINEL = np.int32(2**31 - 1)
+BLOCK_ROWS = 8                     # one (8, 128) tile of f per comparison
 
 
-def _gallop_body(r, f, log2n: int):
-    """Branchless lower_bound of each lane of r into f + membership test."""
-    lo = jnp.full(r.shape, -1, dtype=jnp.int32)
-    for k in range(log2n - 1, -1, -1):               # branchless lower_bound
-        probe = lo + (1 << k)
-        vals = jnp.take(f, probe)                    # vector gather from VMEM
-        lo = jnp.where(vals < r, probe, lo)
-    pos = jnp.minimum(lo + 1, (1 << log2n) - 1)
-    return (jnp.take(f, pos) == r) & (r != SENTINEL)
+def _block_first(f_ref, k):
+    return f_ref[k * BLOCK_ROWS, 0]
 
 
-def make_gallop_kernel(log2n: int):
-    def kernel(r_ref, f_ref, out_ref):
-        r = r_ref[...]                               # (TILE_R,) int32
-        f = f_ref[...]                               # (N,) int32, N = 2**log2n
-        out_ref[...] = _gallop_body(r, f, log2n)
-    return kernel
+def _block_last(f_ref, k):
+    return f_ref[k * BLOCK_ROWS + BLOCK_ROWS - 1, LANES - 1]
 
 
-def make_gallop_kernel_batched(log2n: int):
-    def kernel(r_ref, f_ref, out_ref):
-        r = r_ref[0, :]                              # (TILE_R,) int32
-        f = f_ref[0, :]                              # (N,) this query's long list
-        out_ref[0, :] = _gallop_body(r, f, log2n)
-    return kernel
+def _first_block_reaching(f_ref, k, n_blk: int, x):
+    """Gallop: the first block index ≥ ``k`` whose last value is ≥ ``x``
+    (``n_blk`` when none is).  Every block before ``k`` must end below
+    ``x``."""
+    def grow(s):
+        lo, step = s
+        p = lo + step - 1
+        return (p < n_blk) & (_block_last(f_ref, jnp.minimum(p, n_blk - 1))
+                              < x)
+
+    lo, step = lax.while_loop(grow, lambda s: (s[0] + s[1], s[1] * 2),
+                              (k, jnp.int32(1)))
+
+    def halve(s):
+        lo, hi = s
+        mid = (lo + hi) // 2
+        big = _block_last(f_ref, mid) >= x
+        return jnp.where(big, lo, mid + 1), jnp.where(big, mid, hi)
+
+    lo, _ = lax.while_loop(lambda s: s[0] < s[1], halve,
+                           (lo, jnp.minimum(lo + step - 1, n_blk)))
+    return lo
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def gallop_tiles(r, f, interpret: bool = True):
-    """r: (M,) int32 sentinel-padded, M % 128 == 0; f: (N,) int32 sentinel-
-    padded, N a power of two. Returns (M,) bool match mask."""
-    M, N = r.shape[0], f.shape[0]
-    assert M % TILE_R == 0
-    log2n = int(np.log2(N))
-    assert (1 << log2n) == N, "f must be padded to a power of two"
-    grid_spec = pl.GridSpec(
-        grid=(M // TILE_R,),
-        in_specs=[
-            pl.BlockSpec((TILE_R,), lambda i: (i,)),
-            pl.BlockSpec((N,), lambda i: (0,)),      # f resident in VMEM
-        ],
-        out_specs=pl.BlockSpec((TILE_R,), lambda i: (i,)),
-    )
-    return pl.pallas_call(
-        make_gallop_kernel(log2n),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((M,), jnp.bool_),
-        interpret=interpret,
-    )(r.astype(jnp.int32), f.astype(jnp.int32))
+def probe_tiles(r_ref, f_ref, mask_ref):
+    """AND the membership of every ``r`` lane in ``f`` into ``mask_ref``.
+
+    r_ref: (Tr, 128) int32 sorted ascending, SENTINEL-padded; f_ref:
+    (Rf, 128) int32 sorted ascending, SENTINEL-padded, Rf % 8 == 0;
+    mask_ref: (Tr, 128) int32 0/1 running validity, read for liveness and
+    overwritten with ``mask & (r in f) & (r != SENTINEL)``."""
+    n_blk = f_ref.shape[0] // BLOCK_ROWS
+
+    def tile(t, k):
+        r = r_ref[pl.ds(t, 1), :]                        # (1, 128)
+        live = (mask_ref[pl.ds(t, 1), :] != 0) & (r != SENTINEL)
+        lo_v = jnp.min(jnp.where(live, r, SENTINEL))
+        hi_v = jnp.max(jnp.where(live, r, -1))
+
+        def probe(k):
+            k = _first_block_reaching(f_ref, k, n_blk, lo_v)
+            rows = jnp.broadcast_to(r, (LANES, LANES)).T     # rows[i] = r[i]
+
+            def more(s):
+                kk, _ = s
+                return (kk < n_blk) & (
+                    _block_first(f_ref, jnp.minimum(kk, n_blk - 1)) <= hi_v)
+
+            def compare(s):
+                kk, acc = s
+                blk = f_ref[pl.ds(pl.multiple_of(kk * BLOCK_ROWS,
+                                                 BLOCK_ROWS), BLOCK_ROWS), :]
+                for i in range(BLOCK_ROWS):
+                    acc = acc | (rows == blk[i:i + 1, :]).astype(jnp.int32)
+                return kk + 1, acc
+
+            _, acc = lax.while_loop(
+                more, compare, (k, jnp.zeros((LANES, LANES), jnp.int32)))
+            return k, jnp.max(acc.T, axis=0, keepdims=True)
+
+        k, hit = lax.cond(hi_v >= lo_v, probe,
+                          lambda k: (k, jnp.zeros((1, LANES), jnp.int32)), k)
+        mask_ref[pl.ds(t, 1), :] = jnp.where(live, hit, 0)
+        return k
+
+    lax.fori_loop(0, r_ref.shape[0], tile, jnp.int32(0))
 
 
-@partial(jax.jit, static_argnames=("interpret",))
+# --------------------------------------------------------------------------
+# per-fold entry points: one-fold launches of the megakernels
+# --------------------------------------------------------------------------
+
 def gallop_tiles_batched(r, f, interpret: bool = True):
-    """Batched galloping: r (B, M) sentinel-padded with M % 128 == 0; f (B, N)
-    sentinel-padded, N a power of two.  Grid is (batch row, r-tile); each step
-    holds one query's long list in VMEM and binary-searches a 128-lane tile of
-    its candidates.  Returns (B, M) bool match mask."""
-    B, M = r.shape
-    Bf, N = f.shape
-    assert B == Bf and M % TILE_R == 0
-    log2n = int(np.log2(N))
-    assert (1 << log2n) == N, "f must be padded to a power of two"
-    grid_spec = pl.GridSpec(
-        grid=(B, M // TILE_R),
-        in_specs=[
-            pl.BlockSpec((1, TILE_R), lambda b, i: (b, i)),
-            pl.BlockSpec((1, N), lambda b, i: (b, 0)),   # row-resident f
-        ],
-        out_specs=pl.BlockSpec((1, TILE_R), lambda b, i: (b, i)),
-    )
-    return pl.pallas_call(
-        make_gallop_kernel_batched(log2n),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, M), jnp.bool_),
-        interpret=interpret,
-    )(r.astype(jnp.int32), f.astype(jnp.int32))
+    """Batched galloping: r (B, M) sentinel-padded, M % 128 == 0; f (B, N)
+    sentinel-padded, sorted.  Returns the (B, M) bool match mask."""
+    from repro.kernels import megakernel
+    B = r.shape[0]
+    return megakernel.decoded_fold_batched(
+        r, r != SENTINEL, f[None], jnp.ones((1, B), jnp.int32),
+        interpret=interpret)
 
 
-# --------------------------------------------------------------------------
-# packed gallop: skip-aware partial decode fused with the search
-# --------------------------------------------------------------------------
-#
-# The batched engine never materializes a long compressed list: per batch row
-# the kernel holds the *compressed* words plus per-block metadata in VMEM,
-# gather-decodes only the (host-precomputed, deduplicated) candidate blocks,
-# and binary-searches the whole candidate tile against the partially decoded
-# buffer — decode volume is C·block ints, not the list length (paper §6.5).
-# One grid step per batch row: the decode is done once and all M candidate
-# lanes search it in the same step.
-
-def make_packed_gallop_kernel(mode: str, block_rows: int, n_exc: int):
-    per = block_rows * core_bitpack.LANES
-
-    def kernel(r_ref, w_ref, wid_ref, off_ref, max_ref, blk_ref,
-               ep_ref, ea_ref, out_ref):
-        r = r_ref[0]                                  # (M,) int32
-        C = blk_ref.shape[-1]
-        flat = _bitunpack.decode_candidates(          # (C·per,) sorted int32
-            w_ref[0], wid_ref[0], off_ref[0], max_ref[0], blk_ref[0],
-            ep_ref[0] if n_exc else None, ea_ref[0] if n_exc else None,
-            mode=mode, block_rows=block_rows)
-        log2f = int(np.log2(C * per))
-        out_ref[0] = _gallop_body(r, flat, log2f)
-    return kernel
+def gallop_tiles(r, f, interpret: bool = True):
+    """r (M,) sentinel-padded, M % 128 == 0; f (N,) sorted, sentinel-padded.
+    Returns the (M,) bool match mask."""
+    return gallop_tiles_batched(r[None], f[None], interpret=interpret)[0]
 
 
-@partial(jax.jit, static_argnames=("mode", "block_rows", "interpret"))
 def packed_gallop_batched(r, words, widths, offsets, maxes, blk_ids,
                           exc_pos, exc_add, mode: str, block_rows: int,
                           interpret: bool = True):
-    """Batched skip-aware packed gallop.  r (B, M) sentinel-padded int32;
-    words (B, Tp, 128) uint32; widths/offsets/maxes (B, Kp); blk_ids (B, C)
-    with C·block_rows·128 a power of two; exc_pos/exc_add (B, E) FastPFOR
-    patches (-1-padded).  Returns (B, M) bool match mask."""
-    B, M = r.shape
-    _, C = blk_ids.shape
-    E = exc_pos.shape[-1]
-    per = block_rows * core_bitpack.LANES
-    assert (C * per) & (C * per - 1) == 0, "C·per must be a power of two"
-    Tp, Kp = words.shape[1], widths.shape[1]
-    row = lambda b: (b, 0)
-    row3 = lambda b: (b, 0, 0)
-    grid_spec = pl.GridSpec(
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, M), row),
-            pl.BlockSpec((1, Tp, core_bitpack.LANES), row3),
-            pl.BlockSpec((1, Kp), row),
-            pl.BlockSpec((1, Kp), row),
-            pl.BlockSpec((1, Kp), row),
-            pl.BlockSpec((1, C), row),
-            pl.BlockSpec((1, max(E, 1)), row),
-            pl.BlockSpec((1, max(E, 1)), row),
-        ],
-        out_specs=pl.BlockSpec((1, M), row),
-    )
-    ep = exc_pos if E else jnp.full((B, 1), -1, jnp.int32)
-    ea = exc_add if E else jnp.zeros((B, 1), jnp.uint32)
-    return pl.pallas_call(
-        make_packed_gallop_kernel(mode, block_rows, E),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, M), jnp.bool_),
-        interpret=interpret,
-    )(r.astype(jnp.int32), words, widths.astype(jnp.int32),
-      offsets.astype(jnp.int32), maxes, blk_ids.astype(jnp.int32), ep, ea)
+    """Batched skip-aware packed gallop: decode each row's candidate blocks
+    in kernel scratch and probe them.  r (B, M) sentinel-padded int32;
+    words (B, Tp, 128) uint32; widths/offsets/maxes (B, Kp); blk_ids (B, C);
+    exc_pos/exc_add (B, E) FastPFOR patches (-1-padded).  Returns the
+    (B, M) bool match mask."""
+    from repro.kernels import megakernel
+    B = r.shape[0]
+    return megakernel.packed_fold_batched(
+        r, r != SENTINEL, words[None], widths[None], offsets[None],
+        maxes[None], blk_ids[None], exc_pos[None], exc_add[None],
+        jnp.ones((1, B), jnp.int32), mode=mode, block_rows=block_rows,
+        interpret=interpret)

@@ -1,32 +1,36 @@
 """jit'd public wrappers around the Pallas kernels.
 
 Kernel execution mode (DESIGN.md §2.12): the kernels target TPU v5e, so
-at import we *probe* the runtime backend — compiled Mosaic lowering when
-``jax.default_backend() == "tpu"``, Pallas interpret mode everywhere else
-(this container is CPU-only).  The REPRO_PALLAS_INTERPRET env var is an
-explicit override in either direction (``0`` forces compiled, anything
-else forces interpret); ``set_kernel_mode("compiled"|"interpret"|"auto")``
-re-resolves at runtime (``serve.py --kernel-mode``).  ``INTERPRET`` stays
-the module-level switch every wrapper reads at call time, so existing
+on first use we *probe* the runtime backend — compiled Mosaic lowering
+when ``jax.default_backend() == "tpu"``, Pallas interpret mode everywhere
+else.  The probe is lazy: importing this module touches no backend.  The
+REPRO_PALLAS_INTERPRET env var is an explicit override in either direction
+(``0`` forces compiled, anything else forces interpret);
+``set_kernel_mode("compiled"|"interpret"|"auto")`` re-resolves at runtime
+(``serve.py --kernel-mode``).  ``INTERPRET`` stays the module-level switch
+every wrapper reads at call time (None = not resolved yet), so
 ``ops.INTERPRET = ...`` assignments keep working.  Benchmarks must record
 ``kernel_mode()`` next to any Pallas number — interpret-mode timings are
-not comparable to compiled ones and the bench compare gate refuses to
-ratio across modes.
+not comparable to compiled ones.
+
+VMEM overflow: a launch whose blocks do not fit the kernels' explicit
+scoped-VMEM limit (``megakernel.decoded_fold_fits`` /
+``packed_fold_fits``) runs the jnp reference instead, with identical
+results.  The batch launchers count such folds from the same predicates
+(``stats["kernel_vmem_fallbacks"]``), so the fallback is never silent.
 """
 
 from __future__ import annotations
 
 import os
-from functools import partial
 
-import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from repro.core import bitpack as core_bitpack
 from repro.core import deltas as core_deltas
-from repro.core.intersect import SENTINEL, pad_to, pow2_bucket  # noqa: F401
+from repro.core.intersect import SENTINEL
 from repro.kernels import bitunpack as _bitunpack
 from repro.kernels import bitpack_pack as _bitpack_pack
 from repro.kernels import intersect_gallop as _intersect_gallop
@@ -37,7 +41,7 @@ def probe_kernel_mode() -> str:
     """Capability probe: can the runtime backend execute our Mosaic/TPU
     kernels natively?  Compiled only on TPU — the kernels use TPU grid
     semantics (sequential revisited output blocks, PrefetchScalarGridSpec),
-    so GPU/CPU fall back to interpret."""
+    so GPU/CPU fall back to interpret.  Initializes the JAX backend."""
     return "compiled" if jax.default_backend() == "tpu" else "interpret"
 
 
@@ -54,12 +58,21 @@ def resolve_kernel_mode(mode: str = "auto") -> str:
     return mode
 
 
-INTERPRET = resolve_kernel_mode() == "interpret"
+# None until first use: importing this module must not initialize a JAX
+# backend (that would take the chip as a side effect of an import).
+INTERPRET: bool | None = None
+
+
+def _interpret() -> bool:
+    global INTERPRET
+    if INTERPRET is None:
+        INTERPRET = resolve_kernel_mode() == "interpret"
+    return INTERPRET
 
 
 def kernel_mode() -> str:
     """The effective execution mode of every kernel wrapper in this module."""
-    return "interpret" if INTERPRET else "compiled"
+    return "interpret" if _interpret() else "compiled"
 
 
 def set_kernel_mode(mode: str = "auto") -> str:
@@ -71,7 +84,8 @@ def set_kernel_mode(mode: str = "auto") -> str:
 
 ROWS = _bitunpack.ROWS
 LANES = _bitunpack.LANES
-GALLOP_VMEM_CAP = 1 << 20          # max f ints resident in VMEM (4 MiB)
+decoded_fold_fits = _megakernel.decoded_fold_fits
+packed_fold_fits = _megakernel.packed_fold_fits
 
 
 # --------------------------------------------------------------------------
@@ -98,7 +112,7 @@ def pad_packed(flat_words, offsets):
 
 def unpack_blocks(padded_words, widths, seeds, mode: str = "d1"):
     return _bitunpack.unpack_blocks(padded_words, widths, seeds, mode=mode,
-                                    interpret=INTERPRET)
+                                    interpret=_interpret())
 
 
 def decode_packed(plist: core_bitpack.PackedList) -> jnp.ndarray:
@@ -125,7 +139,7 @@ def decode_packed_ni(plist: core_bitpack.PackedList) -> jnp.ndarray:
 def pack_blocks(values, seeds, widths, mode: str = "d1"):
     """values: (K, 32, 128) uint32 sorted; returns (K, 32, 128) padded words."""
     d = core_deltas.encode_deltas_jnp(values, seeds, mode)
-    return _bitpack_pack.pack_blocks_padded(d, widths, interpret=INTERPRET)
+    return _bitpack_pack.pack_blocks_padded(d, widths, interpret=_interpret())
 
 
 # --------------------------------------------------------------------------
@@ -137,7 +151,7 @@ def flash_attention(q, k, v, *, causal: bool = True, kv_len=None,
     """Flash attention fwd (GQA-aware); see kernels/flash_attention.py."""
     from repro.kernels.flash_attention import flash_attention as _fa
     return _fa(q, k, v, causal=causal, kv_len=kv_len, bq=bq, bk=bk,
-               interpret=INTERPRET)
+               interpret=_interpret())
 
 
 # --------------------------------------------------------------------------
@@ -145,54 +159,45 @@ def flash_attention(q, k, v, *, causal: bool = True, kv_len=None,
 # --------------------------------------------------------------------------
 
 def intersect_gallop(r, f):
-    """Kernel-path galloping intersection; falls back to two-level block-skip
-    probing when f exceeds the VMEM cap (DESIGN.md §2.4)."""
+    """Kernel-path galloping intersection of one (M,) row against a sorted
+    (N,) list; the jnp reference when f does not fit VMEM."""
+    from repro.core import intersect as core_intersect
     M = r.shape[0]
-    m_pad = (-M) % _intersect_gallop.TILE_R
+    m_pad = (-M) % LANES
     if m_pad:
-        r = jnp.concatenate(
-            [r, jnp.full((m_pad,), SENTINEL, dtype=jnp.int32)])
-    N = f.shape[0]
-    n_pow = pow2_bucket(N, floor=_intersect_gallop.TILE_R)
-    if n_pow > GALLOP_VMEM_CAP:
-        from repro.core import intersect as core_intersect
-        mask = core_intersect.intersect_gallop(r, f)
-        return mask[:M]
-    if n_pow != N:
-        f = jnp.concatenate(
-            [f, jnp.full((n_pow - N,), SENTINEL, dtype=jnp.int32)])
-    mask = _intersect_gallop.gallop_tiles(r, f, interpret=INTERPRET)
-    return mask[:M]
+        r = jnp.concatenate([r, jnp.full((m_pad,), SENTINEL, jnp.int32)])
+    if not decoded_fold_fits(r.shape[0], f.shape[0]):
+        return core_intersect.intersect_gallop(r, f)[:M]
+    return _intersect_gallop.gallop_tiles(r, f, interpret=_interpret())[:M]
 
 
 def intersect_gallop_batch(r, f):
     """Kernel-path batched galloping: r (B, M), f (B, N) → (B, M) mask.
-    Inputs must already be sentinel-padded to M % 128 == 0 and N a power of
-    two (index/batch.py buckets guarantee this); falls back to the vmapped
-    jnp path when a query's long list exceeds the VMEM cap."""
-    if f.shape[-1] > GALLOP_VMEM_CAP:
+    r must be sentinel-padded to M % 128 == 0 (index/batch.py buckets
+    guarantee this); the vmapped jnp path when f does not fit VMEM."""
+    if not decoded_fold_fits(r.shape[-1], f.shape[-1]):
         from repro.core import intersect as core_intersect
         return core_intersect.intersect_gallop_batch(r, f)
-    return _intersect_gallop.gallop_tiles_batched(r, f, interpret=INTERPRET)
+    return _intersect_gallop.gallop_tiles_batched(r, f,
+                                                  interpret=_interpret())
 
 
 def intersect_packed_batch(r, words, widths, offsets, maxes, blk_ids,
                            exc_pos, exc_add, mode: str, block_rows: int):
     """Kernel-path batched packed gallop: decode only the candidate blocks of
-    each row's compressed list in VMEM, then binary-search the candidates
-    against the partially decoded buffer (one fused kernel; DESIGN.md §2.6).
-    Falls back to the jnp path when the decoded candidate buffer plus the
-    VMEM-resident compressed words would not fit the VMEM budget."""
-    per = block_rows * LANES
-    resident = blk_ids.shape[-1] * per + words.shape[-2] * LANES
-    if resident > GALLOP_VMEM_CAP:
+    each row's compressed list in VMEM scratch, then probe the seed row
+    against that window (one fused kernel; DESIGN.md §2.6).  The jnp path
+    when the list and its window do not fit VMEM."""
+    if not packed_fold_fits(r.shape[-1], words.shape[-2], widths.shape[-1],
+                            blk_ids.shape[-1], exc_pos.shape[-1],
+                            block_rows):
         from repro.core import intersect as core_intersect
         return core_intersect.intersect_packed_batch(
             r, words, widths, offsets, maxes, blk_ids, exc_pos, exc_add,
             mode=mode, block_rows=block_rows)
     return _intersect_gallop.packed_gallop_batched(
         r, words, widths, offsets, maxes, blk_ids, exc_pos, exc_add,
-        mode=mode, block_rows=block_rows, interpret=INTERPRET)
+        mode=mode, block_rows=block_rows, interpret=_interpret())
 
 
 # --------------------------------------------------------------------------
@@ -200,8 +205,8 @@ def intersect_packed_batch(r, words, widths, offsets, maxes, blk_ids,
 # --------------------------------------------------------------------------
 
 def _fold_scan(r, valid, stack, active, intersect_fn):
-    """VMEM-overflow fallback: per-fold kernel launches under a lax.scan,
-    same mask-fold semantics as the megakernels (and as
+    """VMEM-overflow path: the jnp reference folds under a lax.scan, same
+    mask-fold semantics as the megakernels (and as
     ``batch._mask_fold_scan``, which this mirrors to avoid a circular
     import of the scheduler from the kernel layer)."""
     def step(v, xs):
@@ -216,36 +221,37 @@ def _fold_scan(r, valid, stack, active, intersect_fn):
 def intersect_fold_batch(r, valid, folds, fold_active):
     """Fused decoded SvS fold: ONE kernel launch ANDs the match masks of the
     whole (J, B, N) fold stack into ``valid`` (grid (B, J), the output mask
-    block revisited across j).  Falls back to a scan of per-fold gallop
-    launches when a fold row exceeds the VMEM cap."""
+    block revisited across j).  The jnp reference when a fold row does not
+    fit VMEM."""
     if folds.shape[0] == 0:
         return valid
-    if folds.shape[-1] > GALLOP_VMEM_CAP:
+    if not decoded_fold_fits(r.shape[-1], folds.shape[-1]):
+        from repro.core import intersect as core_intersect
         return _fold_scan(r, valid, folds, fold_active,
-                          intersect_gallop_batch)
+                          core_intersect.intersect_gallop_batch)
     return _megakernel.decoded_fold_batched(r, valid, folds, fold_active,
-                                            interpret=INTERPRET)
+                                            interpret=_interpret())
 
 
 def intersect_packed_fold(r, valid, pk, pk_active, mode: str,
                           block_rows: int):
     """Fused packed SvS fold: ONE kernel launch decodes each (j, b) slot's
-    candidate blocks in VMEM scratch and ANDs the gallop match masks of the
-    whole (Jp, B, ...) packed stack into ``valid`` — no materialized
-    decoded array (DESIGN.md §2.12).  ``pk`` is the stacked operand tuple
-    in ``batch._compose_pk`` order.  Falls back to a scan of per-fold
-    packed-gallop launches when one slot's scratch + resident compressed
-    words exceed the VMEM budget."""
+    candidate blocks in VMEM scratch and ANDs the match masks of the whole
+    (Jp, B, ...) packed stack into ``valid`` — no materialized decoded
+    array (DESIGN.md §2.12).  ``pk`` is the stacked operand tuple in
+    ``batch._compose_pk`` order.  The jnp reference when one slot's
+    compressed list and decode window do not fit VMEM."""
     words, widths, offsets, maxes, blk_ids, exc_pos, exc_add = pk
     if words.shape[0] == 0:
         return valid
-    per = block_rows * LANES
-    resident = blk_ids.shape[-1] * per + words.shape[-2] * LANES
-    if resident > GALLOP_VMEM_CAP:
+    if not packed_fold_fits(r.shape[-1], words.shape[-2], widths.shape[-1],
+                            blk_ids.shape[-1], exc_pos.shape[-1],
+                            block_rows):
+        from repro.core import intersect as core_intersect
         return _fold_scan(
             r, valid, pk, pk_active,
-            lambda rr, op: intersect_packed_batch(
+            lambda rr, op: core_intersect.intersect_packed_batch(
                 rr, *op, mode=mode, block_rows=block_rows))
     return _megakernel.packed_fold_batched(
         r, valid, words, widths, offsets, maxes, blk_ids, exc_pos, exc_add,
-        pk_active, mode=mode, block_rows=block_rows, interpret=INTERPRET)
+        pk_active, mode=mode, block_rows=block_rows, interpret=_interpret())

@@ -12,10 +12,13 @@ count.
 
 The Pallas kernel follows the ``bitunpack.unpack_blocks`` idiom: one grid
 step per block, per-block data byte offset + seed in scalar prefetch
-(SMEM), control words blocked per step, and the full data-word stream
-resident in VMEM across steps (its BlockSpec index map is constant) since
-byte offsets cross block boundaries.  Validated against the host reference
-decode in interpret mode across all delta modes (tests/test_codecs_roundtrip).
+(SMEM), and the control and data streams resident in VMEM across steps
+(constant BlockSpec index maps) since byte offsets cross block
+boundaries.  Mosaic has no general vector gather, so each 128-value row
+gathers its control words and data words lane-wise inside (8, 128) tiles
+(``_gather_lanes``); the delta prefix sum is ``bitunpack.prefix_rows``.
+Validated against the host reference decode in interpret mode across all
+delta modes (tests/test_codecs_roundtrip).
 """
 
 from __future__ import annotations
@@ -25,10 +28,12 @@ from functools import partial
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 
 from repro.core import deltas as core_deltas
 from repro.core import streamvbyte as svb_lib
+from repro.kernels import bitunpack as _bitunpack
 
 LANES = 128
 
@@ -72,22 +77,55 @@ def decode_svb(ctrl, data, doffs, seeds, mode: str, block_rows: int):
     return core_deltas.prefix_sum(d, seeds, mode)
 
 
-def make_svb_kernel(mode: str, block_rows: int, DW: int):
-    """One grid step decodes one block (decode_candidates-style body)."""
-    per = block_rows * LANES
+def _gather_lanes(row, idx):
+    """row[idx] per lane of a (1, 128) int32 row (idx in [0, 128)): Mosaic
+    gathers within a vreg, so both are broadcast to one (8, 128) tile."""
+    return jnp.take_along_axis(jnp.broadcast_to(row, (8, LANES)),
+                               jnp.broadcast_to(idx, (8, LANES)),
+                               axis=1)[:1]
+
+
+def make_svb_kernel(mode: str, block_rows: int, cw: int):
+    """One grid step decodes one block, one 128-value row at a time: the
+    row's 8 control words give 128 2-bit codes, a lane scan of the byte
+    lengths gives each value's byte offset, and the value's two data words
+    come from the ≤ 3 consecutive 128-word data rows the row's bytes can
+    span (a row holds at most 512 bytes)."""
 
     def kernel(doffs_ref, seeds_ref, ctrl_ref, data_ref, out_ref):
         k = pl.program_id(0)
-        base = doffs_ref[k]
-        seed = seeds_ref[k]
-        ctrl = ctrl_ref[0]                         # (CW,) this block's codes
-        data = data_ref[...]                       # (DW,) full stream
-        codes = _codes_of(ctrl, 0, per)
-        lens = codes + 1
-        offs = base + jnp.cumsum(lens) - lens
-        d = _reconstruct(codes, offs, data, DW).reshape(1, block_rows, LANES)
-        out = core_deltas.prefix_sum(d, seed[None], mode)
-        out_ref[0] = out[0]
+        lane = lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+        start = doffs_ref[k]                       # byte offset of the row
+        for r in range(block_rows):
+            cw0 = k * cw + 8 * r                   # first control word
+            ctrl = lax.bitcast_convert_type(
+                ctrl_ref[pl.ds(cw0 // LANES, 1), :], jnp.int32)
+            word = _gather_lanes(ctrl, cw0 % LANES + lane // 16)
+            lens = ((word >> ((lane % 16) * 2)) & 3) + 1
+            offs = start + _bitunpack.lane_scan(lens) - lens
+            q = (start >> 2) // LANES
+            data = [lax.bitcast_convert_type(
+                data_ref[pl.ds(q + i, 1), :], jnp.int32) for i in range(3)]
+
+            def take(i):
+                lo = _gather_lanes(data[0], i % LANES)
+                mid = _gather_lanes(data[1], i % LANES)
+                hi = _gather_lanes(data[2], i % LANES)
+                return lax.bitcast_convert_type(
+                    jnp.where(i < LANES, lo, jnp.where(i < 2 * LANES, mid, hi)),
+                    jnp.uint32)
+
+            li = (offs >> 2) - q * LANES
+            sh = ((offs & 3) << 3).astype(jnp.uint32)
+            lo, hi = take(li), take(li + 1)
+            val = (lo >> sh) | jnp.where(
+                sh > 0, hi << ((jnp.uint32(32) - sh) & 31), jnp.uint32(0))
+            mask = jnp.where(lens >= 4, -1,
+                             (1 << (jnp.minimum(lens, 3) << 3)) - 1)
+            out_ref[pl.ds(r, 1), :] = lax.bitcast_convert_type(
+                val, jnp.int32) & mask
+            start = start + jnp.sum(lens)
+        _bitunpack.prefix_rows(out_ref, 0, block_rows, seeds_ref[k], mode)
 
     return kernel
 
@@ -96,26 +134,38 @@ def make_svb_kernel(mode: str, block_rows: int, DW: int):
 def unpack_svb_blocks(ctrl, data, doffs, seeds, mode: str = "d1",
                       block_rows: int = svb_lib.DEFAULT_ROWS,
                       interpret: bool = True):
-    """Pallas decode: same operands/result as ``decode_svb``."""
+    """Pallas decode: same operands/result as ``decode_svb``.  The control
+    and data streams stay resident in VMEM as (·, 128) rows (data padded by
+    three zero rows so a row's last reads stay in bounds)."""
     from jax.experimental.pallas import tpu as pltpu
+    from repro.kernels.megakernel import compiler_params
 
     K, CW = ctrl.shape
     DW = int(data.shape[0])
+    flat = ctrl.astype(jnp.uint32).reshape(-1)
+    rc = -(-flat.shape[0] // LANES)
+    flat = jnp.pad(flat, (0, rc * LANES - flat.shape[0]))
+    rd = -(-DW // LANES) + 3
+    words = jnp.pad(data.astype(jnp.uint32), (0, rd * LANES - DW))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                     # doffs, seeds → SMEM
         grid=(K,),
-        in_specs=[pl.BlockSpec((1, CW), lambda k, *_: (k, 0)),
-                  pl.BlockSpec((DW,), lambda k, *_: (0,))],
-        out_specs=pl.BlockSpec((1, block_rows, LANES),
+        in_specs=[pl.BlockSpec((rc, LANES), lambda k, *_: (0, 0)),
+                  pl.BlockSpec((rd, LANES), lambda k, *_: (0, 0))],
+        out_specs=pl.BlockSpec((None, block_rows, LANES),
                                lambda k, *_: (k, 0, 0)),
     )
-    return pl.pallas_call(
-        make_svb_kernel(mode, block_rows, DW),
+    out = pl.pallas_call(
+        make_svb_kernel(mode, block_rows, CW),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((K, block_rows, LANES), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((K, block_rows, LANES), jnp.int32),
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(doffs.astype(jnp.int32), seeds.astype(jnp.uint32),
-      ctrl.astype(jnp.uint32), data.astype(jnp.uint32))
+        name="svb_decode",
+    )(doffs.astype(jnp.int32),
+      lax.bitcast_convert_type(seeds.astype(jnp.uint32), jnp.int32),
+      flat.reshape(rc, LANES), words.reshape(rd, LANES))
+    return lax.bitcast_convert_type(out, jnp.uint32)
 
 
 def _pow2(n: int) -> int:

@@ -5,14 +5,9 @@ never touches jax device state.  The dry-run sets
 XLA_FLAGS=--xla_force_host_platform_device_count=512 *before* any jax import;
 tests and benchmarks see the real single device.
 
-AxisType compatibility: newer jax exposes ``jax.sharding.AxisType`` and
-``jax.make_mesh(..., axis_types=...)``; the pinned jax 0.4.37 has
-``jax.make_mesh`` but no AxisType.  Mesh construction therefore only passes
-``axis_types`` when the running jax provides it — every mesh here is
-Auto-typed anyway, which is exactly what an axis-type-free mesh means, so
-the two paths are semantically identical.  This is what lets the sharded
-index executor (``repro.index.shard``) and the multi-device tests run under
-``--xla_force_host_platform_device_count`` on the pinned jax.
+Every mesh here is Auto-typed (``jax.sharding.AxisType.Auto``), which is
+what the sharded index executor (``repro.index.shard``) and the
+multi-device tests under ``--xla_force_host_platform_device_count`` need.
 """
 
 from __future__ import annotations
@@ -21,17 +16,10 @@ import numpy as np
 
 import jax
 
-try:                                    # jax >= 0.6
-    from jax.sharding import AxisType as _AxisType
-except ImportError:                     # pinned jax 0.4.37: Auto is implicit
-    _AxisType = None
-
 
 def _mk(shape, axes):
-    if _AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(_AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -760,6 +760,8 @@ def main():
                          "(realistic cache hit rates)")
     ap.add_argument("--tokens", type=int, default=16)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.arch == "paper-index":
         return serve_index(args)
     spec = get_config(args.arch)

@@ -650,9 +650,10 @@ def warm_server(server: ContinuousBatchingServer,
     sized* (1..max_batch), and every ladder bucket a flush lands in is a
     distinct program signature — an offline warm at one fixed batch size
     leaves all the smaller buckets cold, which is exactly the hidden
-    compile tail a p99 report would eat.  Returns the same dict shape as
-    ``batch.warmup`` (n_compiles / n_signatures / passes / converged /
-    time_s)."""
+    compile tail a p99 report would eat.  A drain-mode server flushes
+    only the stream's contiguous full batches, so it warms those alone.
+    Returns the same dict shape as ``batch.warmup`` (n_compiles /
+    n_signatures / passes / converged / time_s)."""
     t0 = time.perf_counter()
     c0 = batch_lib._compile_count()
     if queries is None:
@@ -665,9 +666,11 @@ def warm_server(server: ContinuousBatchingServer,
         queries = batch_lib.synth_warmup_queries(
             view, 2 * server.max_batch, seed=seed)
 
-    # every ×1.5-ladder bucket a 1..max_batch flush can land in
+    # every ×1.5-ladder bucket a 1..max_batch flush can land in — except in
+    # drain mode, whose flushes are exactly the stream's contiguous
+    # max_batch chunks (plus the remainder), so that walk covers them all
     sizes, b = [], 1
-    while b < batch_lib._bucket_rows(server.max_batch):
+    while not server.drain and b < batch_lib._bucket_rows(server.max_batch):
         sizes.append(b)
         b = b * 3 // 2 if b >= 2 else b + 1
     sizes.append(server.max_batch)
@@ -756,11 +759,19 @@ def main(argv=None):
                          "(see launch/faults.py)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--shared-vocab", action="store_true")
+    ap.add_argument("--n-docs", type=int, default=1 << 16,
+                    help="corpus size in documents; Table 2 list lengths "
+                         "scale with it (ClueWeb09 Category B is "
+                         "50,000,000)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.index import builder, corpus as corpus_lib, source
-    corpus = corpus_lib.synthesize(n_docs=1 << 16, n_queries=args.queries,
-                                   seed=5, shared_vocab=args.shared_vocab)
+    t0 = time.perf_counter()
+    corpus = corpus_lib.synthesize(n_docs=args.n_docs,
+                                   n_queries=args.queries, seed=5,
+                                   shared_vocab=args.shared_vocab)
     injector = (faults_lib.FaultInjector(args.chaos, seed=args.seed)
                 if args.chaos else None)
     kw = dict(backend=args.backend, max_batch=args.batch,
@@ -780,6 +791,10 @@ def main(argv=None):
             pool = source.ResidentPool()
             pool.warm(idx)
             kw["pool"] = pool
+    print(f"[server] corpus {corpus.n_docs} docs, "
+          f"{sum(int(p.shape[0]) for p in corpus.postings)} postings; "
+          f"index built and staged in {time.perf_counter() - t0:.1f}s",
+          flush=True)
     results, server = serve_open_loop(idx, corpus.queries, qps=args.qps,
                                       pattern=args.pattern, seed=args.seed,
                                       warmup=args.warmup, **kw)
@@ -811,6 +826,10 @@ def main(argv=None):
           f"{server.stats.get('n_compiles', 0)} compiles")
     print(f"[server]   queue depth histogram (pow2 buckets): "
           f"{s['queue_depth_hist']}")
+    st = server.stats
+    print(f"[server]   folds: {st.get('folds', 0)} "
+          f"({st.get('kernel_folds', 0)} in a Pallas kernel, "
+          f"{st.get('kernel_vmem_fallbacks', 0)} VMEM fallbacks to jnp)")
     lad = server.ladder
     if (s["n_timeout"] or s["n_errors"] or s["n_faults"]
             or lad.n_degradations or injector is not None):
@@ -834,7 +853,7 @@ def main(argv=None):
                 np.array_equal(got.docs, want.docs), f"mismatch on {q}"
         print(f"[server] differential check: {len(served)} served results "
               f"byte-identical to offline execute_batch")
-    return results, server
+    return results, server, corpus
 
 
 if __name__ == "__main__":

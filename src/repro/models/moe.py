@@ -25,19 +25,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-try:                                    # jax>=0.7 exposes it at top level
-    shard_map = jax.shard_map
-except AttributeError:                  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-# jax >= 0.7 renamed the replication-check kwarg check_rep -> check_vma
-import inspect as _inspect
-_SHARD_MAP_NO_CHECK = (
-    {"check_vma": False}
-    if "check_vma" in _inspect.signature(shard_map).parameters
-    else {"check_rep": False})
-
-
 def init_moe_params(rng, d_model: int, d_ff: int, n_experts: int,
                     dtype=jnp.float32):
     k1, k2, k3, k4 = jax.random.split(rng, 4)
@@ -210,11 +197,11 @@ def moe_ffn_sharded(params, x, *, top_k: int, capacity_factor: float,
         return out.reshape(xb.shape), aux
 
     espec = P("model", None, None)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(None, None), espec, espec, espec,
-                             P(dp, "model", None)),
-                   out_specs=(P(dp, "model", None), P()),
-                   **_SHARD_MAP_NO_CHECK)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(None, None), espec, espec, espec,
+                                 P(dp, "model", None)),
+                       out_specs=(P(dp, "model", None), P()),
+                       check_vma=False)
     return fn(params["router"], params["w_in"], params["w_gate"],
               params["w_out"], x)
 

@@ -83,12 +83,20 @@ def test_streamvbyte_control_stream_layout():
 def test_streamvbyte_pallas_kernel_matches_host(mode):
     from repro.kernels import svb_decode
     rng = np.random.default_rng(11)
-    for n in (1, 300, 1024, 4096):
+    for n, rows, kernel in ((1, 1, False), (300, 1, True), (1024, 1, False),
+                            (4096, 1, False), (3000, 8, True)):
         vals = np.sort(rng.choice(1 << 28, n, replace=False)).astype(np.int64)
-        sl = streamvbyte.encode(vals, mode=mode)
+        sl = streamvbyte.encode(vals, mode=mode, block_rows=rows)
         host = streamvbyte.decode_np(sl)
         dev = np.asarray(svb_decode.decode_bucketed(sl))[: sl.n]
         np.testing.assert_array_equal(dev.astype(np.int64), host)
+        if not kernel:
+            continue
+        seeds = np.concatenate([[0], sl.maxes[:-1]]).astype(np.uint32)
+        kern = np.asarray(svb_decode.unpack_svb_blocks(
+            sl.ctrl, sl.data, sl.doffs, seeds, mode=mode, block_rows=rows,
+            interpret=True)).reshape(-1)[: sl.n]
+        np.testing.assert_array_equal(kern.astype(np.int64), host)
 
 
 def test_composite_head_tail_split():

@@ -89,11 +89,27 @@ def test_intersect_kernel_sweep(m, n, rng):
 
 
 def test_kernel_vmem_budget():
-    """BlockSpec working sets stay under TPU v5e VMEM (16 MiB)."""
-    unpack_ws = 2 * 32 * 128 * 4                   # in+out tiles
-    assert unpack_ws < 16 * 2**20
-    gallop_ws = ops.GALLOP_VMEM_CAP * 4 + 2 * kb.LANES * 4
-    assert gallop_ws <= 8 * 2**20                  # f table + r tile
+    """The fit predicates are derived from the explicit scoped-VMEM limit,
+    which itself stays under TPU v5e's 128 MiB of VMEM: double-buffered
+    (rows, 128) int32 blocks (sublanes padded to 8) plus scratch plus the
+    headroom must fit, and one int past the limit must not."""
+    from repro.kernels import megakernel as mk
+    assert mk.VMEM_LIMIT_BYTES < 128 * 2**20
+    unpack_ws = 2 * 2 * 32 * 128 * 4               # in+out tiles, 2 buffers
+    assert mk.fits_vmem([32, 32])
+    assert unpack_ws < mk.VMEM_LIMIT_BYTES
+    budget = mk.VMEM_LIMIT_BYTES - mk.VMEM_HEADROOM_BYTES
+    m = 1 << 14                                    # seed row + 2 mask rows
+    fixed = 2 * 3 * m * 4
+    n_max = (budget - fixed) // (2 * 4)            # largest fold that fits
+    n_max -= n_max % 1024
+    assert ops.decoded_fold_fits(m, n_max)
+    assert not ops.decoded_fold_fits(m, n_max + 1024)
+    # a 1-row tile pads to 8 sublanes
+    assert mk._tile_bytes(1) == mk._tile_bytes(8) == 8 * kb.LANES * 4
+    # the packed window is single-buffered scratch on top of the blocks
+    assert ops.packed_fold_fits(m, 1024, 256, 64, 0, 32)
+    assert not ops.packed_fold_fits(m, 1024, 256, 1 << 16, 0, 32)
 
 
 def test_pad_packed_empty_payload():

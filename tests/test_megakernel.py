@@ -399,3 +399,22 @@ def test_kernel_mode_resolution():
         else:
             os.environ["REPRO_PALLAS_INTERPRET"] = prev_env
         kernel_ops.INTERPRET = prev
+
+
+def test_import_initializes_no_backend():
+    """Importing the kernel wrappers, the scheduler or the server must not
+    initialize a JAX backend: on a TPU host that would take the chip as a
+    side effect of an import.  Checked in a fresh interpreter."""
+    import subprocess
+    import sys
+    code = ("import repro.kernels.ops, repro.index.batch, "
+            "repro.launch.server\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge._backends, list(xla_bridge._backends)\n"
+            "assert repro.kernels.ops.INTERPRET is None\n")
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    env = dict(os.environ, PYTHONPATH=src)
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]

@@ -10,11 +10,6 @@ import textwrap
 
 import pytest
 
-# repro.launch.mesh is AxisType-free since PR 4: it only passes axis_types
-# when the running jax provides it, so these subprocess checks run on the
-# pinned jax 0.4.37 too.  No test in this file needs AxisType itself —
-# if one ever does, skip that test alone with a comment naming the API.
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 

@@ -5,8 +5,7 @@ Layers:
     the same query multiset across {jax, pallas} × {fused, unfused} ×
     shards {1, 2}, in drain and live (open-loop) modes, under any arrival
     order,
-  * steady state: a warmed server reports n_compiles == 0 (skipped — not
-    vacuously passed — when jax lacks jit ``_cache_size``),
+  * steady state: a warmed server reports n_compiles == 0,
   * the three loop policies: family-aligned admission after warmup,
     deadline flush vs full flush, bounded-queue shedding,
   * unit coverage for the arrival processes, ``batch.plan_covers`` and the
@@ -124,9 +123,6 @@ def test_server_pool_composes(uniform):
 # --------------------------------------------------------------------------
 
 def test_server_steady_state_zero_compiles(mixed):
-    if getattr(batch_lib._svs_program, "_cache_size", None) is None:
-        pytest.skip("this jax does not expose jit _cache_size — compile "
-                    "accounting unavailable (would pass vacuously)")
     idx, queries, seq = mixed
     pool = source.ResidentPool()
     pool.warm(idx)
@@ -254,10 +250,6 @@ def _mutable_setup(n_queries=16, seed=7):
     return mi, corpus, terms
 
 
-def _compile_accounting_available():
-    return getattr(batch_lib._svs_program, "_cache_size", None) is not None
-
-
 def test_server_live_mutation_windows_match_offline():
     """Rounds of adds/deletes between Poisson serving windows: every
     window's served results equal offline ``MutableIndex.execute_batch``
@@ -270,7 +262,6 @@ def test_server_live_mutation_windows_match_offline():
         stats=stats)
     wu = server_lib.warm_server(srv, corpus.queries)
     assert wu["converged"]
-    check_compiles = _compile_accounting_available()
     rng = np.random.default_rng(2)
 
     def mutate(n_adds=20, n_dels=5):
@@ -289,7 +280,7 @@ def test_server_live_mutation_windows_match_offline():
         assert srv.metrics.n_shed == 0
         offline = mi.execute_batch(corpus.queries)
         _assert_identical(results, offline)
-        if steady and check_compiles:
+        if steady:
             assert stats.get("n_compiles", 0) == 0
 
     # window 0 converges the plan: the AOT ladder warms contiguous chunk
