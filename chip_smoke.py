@@ -2,13 +2,13 @@
 
 Drives the normal serving entry point (``repro.launch.server.main``: the
 continuous-batching server over a device-resident index, fused programs,
-AOT warmup) on the paper's deployment — the ClueWeb09 Category B shape
-with its Table 2 (TREC Million Query) list-length marginals and a
-Zipf-shared query vocabulary, at GOV2's 25,205,179 documents (the time
-limit forces the cut from ClueWeb09-B's 50,000,000) — once as a drain backlog and once under Poisson arrivals, for the
-jax and the pallas backends.  Every phase must
-serve with no error, fault, timeout, degradation, low-occupancy fallback
-or steady-state compile, and its answers must equal a plain numpy
+AOT warmup) on the paper's deployment — ClueWeb09 Category B's
+50,000,000 documents with its Table 2 (TREC Million Query) list-length
+marginals and a Zipf-shared query vocabulary — once as a drain backlog
+and once under Poisson arrivals, for the jax and the pallas backends, all
+four phases over the same corpus and query log.  Every phase must serve
+with no error, fault, timeout, degradation, low-occupancy fallback or
+steady-state compile, and every answer must equal a plain numpy
 reference (``np.intersect1d`` folded over the posting lists) byte for
 byte.  The q/s and latency it prints are a smoke, not a benchmark.
 
@@ -34,13 +34,12 @@ import time
 from functools import reduce
 
 CLUEWEB09B_DOCS = 50_000_000       # index/corpus.py TABLE2_DOCS
-GOV2_DOCS = 25_205_179             # the paper's other corpus
-REF_QUERIES = 64                   # queries checked against numpy
-# (queries, max batch) per backend.  The jax backend's gather-bound
-# searchsorted serves about 1 q/s at 50M docs on one v5e, and the live
-# warmup runs every query once per batch-size bucket, so it gets fewer
-# queries and a shorter bucket ladder to finish inside the time limit.
-PHASES = {"jax": (16, 4), "pallas": (128, 32)}
+QUERIES = 64                       # one query log, checked whole per phase
+# Max batch per backend.  A Poisson-phase warm-up runs every query once
+# per batch-size bucket up to the max batch, and the jax backend's
+# gather-bound searchsorted is slow at this size, so jax flushes one
+# query at a time.
+BATCH = {"jax": 1, "pallas": 32}
 
 
 def fail(msg: str):
@@ -145,20 +144,29 @@ def corpus_line(corpus, n_docs: int):
         f"terms the query log uses), {len(corpus.queries)} queries")
 
 
-def one_chip(server_lib, device, n_docs: int = GOV2_DOCS):
-    largest = None
-    for backend, (queries, batch) in PHASES.items():
-        argv = ["--n-docs", str(n_docs), "--queries", str(queries),
-                "--shared-vocab", "--resident", "--warmup",
-                "--batch", str(batch), "--seed", "0", "--backend", backend]
+def reference_for(corpus, n_docs: int):
+    corpus_line(corpus, n_docs)
+    t0 = time.perf_counter()
+    ref = numpy_reference(corpus, range(len(corpus.queries)))
+    say(f"numpy reference for {len(ref)} queries in "
+        f"{time.perf_counter() - t0:.1f} s")
+    return ref
+
+
+def serve_args(n_docs: int, backend: str) -> list[str]:
+    return ["--n-docs", str(n_docs), "--queries", str(QUERIES),
+            "--shared-vocab", "--warmup", "--batch", str(BATCH[backend]),
+            "--seed", "0", "--backend", backend]
+
+
+def one_chip(server_lib, device, n_docs: int = CLUEWEB09B_DOCS):
+    ref = largest = None
+    for backend in BATCH:
+        argv = serve_args(n_docs, backend) + ["--resident"]
         results, server, corpus, s = serve_phase(
             server_lib, argv, f"{backend}/drain", device)
-        corpus_line(corpus, n_docs)
-        qids = list(range(min(REF_QUERIES, len(corpus.queries))))
-        t0 = time.perf_counter()
-        ref = numpy_reference(corpus, qids)
-        say(f"numpy reference for {len(qids)} queries in "
-            f"{time.perf_counter() - t0:.1f} s")
+        if ref is None:
+            ref = reference_for(corpus, n_docs)
         check_against(results, ref, server.max_results, f"{backend}/drain")
         say(f"{backend}/drain: {len(ref)} queries byte-identical to the "
             f"numpy reference")
@@ -181,19 +189,14 @@ def one_chip(server_lib, device, n_docs: int = GOV2_DOCS):
     say(f"largest fused signature: {json.dumps(largest)}")
 
 
-def four_chips(server_lib, devices, n_docs: int = GOV2_DOCS):
+def four_chips(server_lib, devices, n_docs: int = CLUEWEB09B_DOCS):
     import numpy as np
     if len(devices) < 4:
         fail(f"--four-chips needs 4 devices, JAX found {len(devices)}")
-    queries, batch = PHASES["pallas"]
-    base = ["--n-docs", str(n_docs), "--queries", str(queries),
-            "--shared-vocab", "--warmup", "--batch", str(batch),
-            "--seed", "0", "--backend", "pallas"]
+    base = serve_args(n_docs, "pallas")
     one, server1, corpus, _ = serve_phase(
         server_lib, base + ["--resident"], "1 chip/drain", devices[0])
-    corpus_line(corpus, n_docs)
-    ref = numpy_reference(corpus, range(min(REF_QUERIES,
-                                            len(corpus.queries))))
+    ref = reference_for(corpus, n_docs)
     check_against(one, ref, server1.max_results, "1 chip/drain")
     del server1, corpus
     gc.collect()
@@ -212,8 +215,10 @@ def four_chips(server_lib, devices, n_docs: int = GOV2_DOCS):
         where = {d for b in bufs for d in b.devices()}
         if where != {pool.device}:
             fail(f"shard {sid} buffers on {where}, expected {pool.device}")
+        peak = (pool.device.memory_stats() or {}).get("peak_bytes_in_use")
         say(f"shard {sid} -> {pool.device}: {len(bufs)} resident buffers, "
-            f"{pool.device_ints():,} ints, all on its device")
+            f"{pool.device_ints():,} ints, all on its device; "
+            f"peak_bytes_in_use {peak}")
     for qi, (a, b) in enumerate(zip(one, four)):
         if a is None or b is None or a.count != b.count or \
                 not np.array_equal(a.docs, b.docs):

@@ -744,31 +744,28 @@ def _effective_backend(key: GroupKey, items: list[_Item], backend: str,
     return backend
 
 
-def count_folds(stats: dict | None, key: GroupKey, items: list,
-                backend: str):
+def count_folds(stats: dict | None, items: list, backend: str, r, folds,
+                pk, block_rows: int):
     """Count the real folds one svs launch runs, and where: ``folds`` all
     of them; under the pallas backend ``kernel_folds`` those a megakernel
-    runs and ``kernel_vmem_fallbacks`` those whose shapes do not fit the
-    kernels' VMEM limit and so run the jnp reference (the same
-    ``kernels.ops`` predicates the program routes on)."""
+    runs and ``kernel_vmem_fallbacks`` those whose operands overflow the
+    kernels' VMEM limit and so run the jnp reference.  ``r``/``folds``/
+    ``pk`` are the launch's own operands, routed by the same
+    ``kernels.ops`` functions the program branches on."""
     if stats is None:
         return
     items = [it for it in items if it is not None]
     dec = sum(len(it.folds or ()) for it in items)
-    pk = sum(len(it.psrc or ()) for it in items)
-    source._bump(stats, "folds", dec + pk)
+    pk_n = sum(len(it.psrc or ()) for it in items)
+    source._bump(stats, "folds", dec + pk_n)
     if backend != "pallas":
         return
     from repro.kernels import ops as kernel_ops
-    kernel = dec if kernel_ops.decoded_fold_fits(key.m_bucket,
-                                                 key.n_bucket) else 0
-    if pk:
-        k_pad, t_pad, c_pad, e_pad, rows, _ = key.packed
-        if kernel_ops.packed_fold_fits(key.m_bucket, t_pad, k_pad, c_pad,
-                                       e_pad, rows):
-            kernel += pk
+    kernel = dec if dec and kernel_ops.decoded_in_kernel(r, folds) else 0
+    if pk_n and kernel_ops.packed_in_kernel(r, pk, block_rows):
+        kernel += pk_n
     source._bump(stats, "kernel_folds", kernel)
-    source._bump(stats, "kernel_vmem_fallbacks", dec + pk - kernel)
+    source._bump(stats, "kernel_vmem_fallbacks", dec + pk_n - kernel)
 
 
 def _launch_svs_group(key: GroupKey, items: list[_Item], backend: str,
@@ -780,7 +777,6 @@ def _launch_svs_group(key: GroupKey, items: list[_Item], backend: str,
     ``pipeline.StageTimings``) splits operand assembly from the async
     program enqueue."""
     backend = _effective_backend(key, items, backend, stats)
-    count_folds(stats, key, items, backend)
     t0 = time.perf_counter()
     R, F, active, pkparts, W, Bp, J, Jb = _assemble_svs(key, items, pool)
     pk = pk_active = None
@@ -798,6 +794,7 @@ def _launch_svs_group(key: GroupKey, items: list[_Item], backend: str,
         source._bump(stats, "decoded_ints",
                      sum(len(it.psrc) for it in items)
                      * key.packed[2] * rows * 128)
+    count_folds(stats, items, backend, R, F, pk, rows)
     if stats is not None:
         stats.setdefault("signatures", set()).add(("svs", key, Bp, J, Jb))
     t1 = time.perf_counter()

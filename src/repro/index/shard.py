@@ -257,7 +257,7 @@ def _launch_svs_sharded(sharded: ShardedIndex, key, per_shard: list,
     # the sharded grid's S·Bq batch rows
     backend = batch_lib._effective_backend(key, all_items, backend, stats,
                                            bp=S * Bq)
-    batch_lib.count_folds(stats, key, all_items, backend)
+    batch_lib.count_folds(stats, all_items, backend, R, F, pk, rows)
     vals, counts = batch_lib._svs_program(
         R, F, active, pk, pk_active, W, key.algo, backend, mode, rows,
         mesh=sharded.mesh if len(sharded.devices) > 1 else None)

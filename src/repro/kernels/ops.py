@@ -14,9 +14,9 @@ every wrapper reads at call time (None = not resolved yet), so
 not comparable to compiled ones.
 
 VMEM overflow: a launch whose blocks do not fit the kernels' explicit
-scoped-VMEM limit (``megakernel.decoded_fold_fits`` /
-``packed_fold_fits``) runs the jnp reference instead, with identical
-results.  The batch launchers count such folds from the same predicates
+scoped-VMEM limit (``decoded_in_kernel`` / ``packed_in_kernel``) runs the jnp reference
+instead, with identical results.  The batch launchers count such folds
+with the same two functions on the same operands
 (``stats["kernel_vmem_fallbacks"]``), so the fallback is never silent.
 """
 
@@ -86,6 +86,24 @@ ROWS = _bitunpack.ROWS
 LANES = _bitunpack.LANES
 decoded_fold_fits = _megakernel.decoded_fold_fits
 packed_fold_fits = _megakernel.packed_fold_fits
+
+
+def decoded_in_kernel(r, f) -> bool:
+    """Where a decoded fold of seed rows ``r`` (..., M) against sorted
+    lists ``f`` (..., N) runs: True in a Pallas kernel, False in the jnp
+    reference (its blocks overflow the VMEM limit).  The one routing
+    rule: the wrappers below branch on it and ``index.batch.count_folds``
+    counts folds with it."""
+    return decoded_fold_fits(r.shape[-1], f.shape[-1])
+
+
+def packed_in_kernel(r, pk, block_rows: int) -> bool:
+    """``decoded_in_kernel`` for a packed fold: ``pk`` is the operand tuple
+    in ``batch._compose_pk`` order (words, widths, offsets, maxes, blk_ids,
+    exc_pos, exc_add)."""
+    words, widths, _, _, blk_ids, exc_pos, _ = pk
+    return packed_fold_fits(r.shape[-1], words.shape[-2], widths.shape[-1],
+                            blk_ids.shape[-1], exc_pos.shape[-1], block_rows)
 
 
 # --------------------------------------------------------------------------
@@ -166,7 +184,7 @@ def intersect_gallop(r, f):
     m_pad = (-M) % LANES
     if m_pad:
         r = jnp.concatenate([r, jnp.full((m_pad,), SENTINEL, jnp.int32)])
-    if not decoded_fold_fits(r.shape[0], f.shape[0]):
+    if not decoded_in_kernel(r, f):
         return core_intersect.intersect_gallop(r, f)[:M]
     return _intersect_gallop.gallop_tiles(r, f, interpret=_interpret())[:M]
 
@@ -175,7 +193,7 @@ def intersect_gallop_batch(r, f):
     """Kernel-path batched galloping: r (B, M), f (B, N) → (B, M) mask.
     r must be sentinel-padded to M % 128 == 0 (index/batch.py buckets
     guarantee this); the vmapped jnp path when f does not fit VMEM."""
-    if not decoded_fold_fits(r.shape[-1], f.shape[-1]):
+    if not decoded_in_kernel(r, f):
         from repro.core import intersect as core_intersect
         return core_intersect.intersect_gallop_batch(r, f)
     return _intersect_gallop.gallop_tiles_batched(r, f,
@@ -188,9 +206,8 @@ def intersect_packed_batch(r, words, widths, offsets, maxes, blk_ids,
     each row's compressed list in VMEM scratch, then probe the seed row
     against that window (one fused kernel; DESIGN.md §2.6).  The jnp path
     when the list and its window do not fit VMEM."""
-    if not packed_fold_fits(r.shape[-1], words.shape[-2], widths.shape[-1],
-                            blk_ids.shape[-1], exc_pos.shape[-1],
-                            block_rows):
+    if not packed_in_kernel(r, (words, widths, offsets, maxes, blk_ids,
+                                exc_pos, exc_add), block_rows):
         from repro.core import intersect as core_intersect
         return core_intersect.intersect_packed_batch(
             r, words, widths, offsets, maxes, blk_ids, exc_pos, exc_add,
@@ -225,7 +242,7 @@ def intersect_fold_batch(r, valid, folds, fold_active):
     fit VMEM."""
     if folds.shape[0] == 0:
         return valid
-    if not decoded_fold_fits(r.shape[-1], folds.shape[-1]):
+    if not decoded_in_kernel(r, folds):
         from repro.core import intersect as core_intersect
         return _fold_scan(r, valid, folds, fold_active,
                           core_intersect.intersect_gallop_batch)
@@ -244,9 +261,7 @@ def intersect_packed_fold(r, valid, pk, pk_active, mode: str,
     words, widths, offsets, maxes, blk_ids, exc_pos, exc_add = pk
     if words.shape[0] == 0:
         return valid
-    if not packed_fold_fits(r.shape[-1], words.shape[-2], widths.shape[-1],
-                            blk_ids.shape[-1], exc_pos.shape[-1],
-                            block_rows):
+    if not packed_in_kernel(r, pk, block_rows):
         from repro.core import intersect as core_intersect
         return _fold_scan(
             r, valid, pk, pk_active,

@@ -367,6 +367,34 @@ def test_occupancy_fallback_results_identical():
         assert a.count == b.count and np.array_equal(a.docs, b.docs)
 
 
+@pytest.mark.parametrize("fits", [True, False])
+def test_fold_counts_follow_kernel_routing(monkeypatch, fits):
+    """Every pallas fold is counted where the program routes it: in a
+    kernel when its operands fit VMEM, as a VMEM fallback when they do
+    not — with results identical to the jax backend either way."""
+    monkeypatch.setattr(batch_lib, "PALLAS_MIN_OCCUPANCY", 0.0)
+    if not fits:
+        monkeypatch.setattr(kernel_ops, "decoded_fold_fits",
+                            lambda *a: False)
+        monkeypatch.setattr(kernel_ops, "packed_fold_fits",
+                            lambda *a: False)
+    corpus = corpus_lib.synthesize(n_docs=1 << 14, n_queries=8, seed=3)
+    idx = builder.build(corpus.postings, corpus.n_docs,
+                        codec_name="fastpfor-d1", B=16, n_parts=2)
+    stats: dict = {}
+    got = batch_lib.execute_batch(idx, corpus.queries, backend="pallas",
+                                  stats=stats)
+    ref = batch_lib.execute_batch(idx, corpus.queries, backend="jax")
+    for a, b in zip(got, ref):
+        assert a.count == b.count and np.array_equal(a.docs, b.docs)
+    assert stats["folds"] > 0
+    in_kernel = stats.get("kernel_folds", 0)
+    fell_back = stats.get("kernel_vmem_fallbacks", 0)
+    assert in_kernel + fell_back == stats["folds"]
+    assert (in_kernel, fell_back) == ((stats["folds"], 0) if fits
+                                      else (0, stats["folds"]))
+
+
 # --------------------------------------------------------------------------
 # kernel-mode probe / override resolution
 # --------------------------------------------------------------------------

@@ -28,8 +28,8 @@ from repro.kernels import ops as kernel_ops
 from repro.kernels import svb_decode
 
 # largest fused svs signature of the full-size (50M-doc) warm-up on a v5e:
-# seed rows M, fold rows N, J decoded folds, JB bitmaps of W words.  The
-# 25.2M-doc chip_smoke.py prints half these M, N and W with Bp <= 6.  No
+# seed rows M, fold rows N, J decoded folds, JB bitmaps of W words, as
+# chip_smoke.py prints them, with Bp <= 2 there.  No
 # packed family forms at that size; the packed shapes are a mid-size list
 # (4096 word rows, 512 blocks, a 512-block candidate window).
 B, M, N, J, JB, W = 8, 1 << 19, 1 << 21, 4, 4, 781250
