@@ -91,8 +91,8 @@ the ratio of two same-run numbers, so the gate tracks the engine, not the
 absolute speed of the runner it happens to execute on.  ``--max-dispatches
 N`` gates the fused dispatches-per-batch count the same way (a regression
 back to per-signature dispatch fails fast), and ``--profile`` prints the
-per-batch schedule / assemble / dispatch / device breakdown of the fused
-resident pipeline.
+schedule / assemble / dispatch / collect totals of the fused resident
+pipeline, from ``repro.trace``.
 """
 
 from __future__ import annotations
@@ -304,12 +304,13 @@ def _dispatch(quick: bool) -> None:
 
 
 def _profile(quick: bool) -> None:
-    """--profile: per-batch schedule / assemble / dispatch / device-block
-    breakdown of the resident fused pipeline, so the next PR can see where
-    the next bottleneck sits without re-instrumenting."""
+    """--profile: schedule / assemble / dispatch / collect totals of the
+    resident pipeline, fused and unfused, from the recorder's spans."""
+    from repro import trace
     from repro.index import builder, corpus as corpus_lib, source
     from repro.index import batch as batch_lib
     from repro.index import pipeline as pipe_lib
+    from repro.launch.serve import stage_line
 
     table = {k: corpus_lib.TABLE2_CLUEWEB[k] for k in (2, 3, 4, 5)}
     n_docs = 1 << 14 if quick else 1 << 16
@@ -324,20 +325,12 @@ def _profile(quick: bool) -> None:
     plan = batch_lib.FusionPlan()
     batch_lib.warmup(idx, queries, plan=plan, batch_size=32, pool=pool)
     for fuse in (True, False):
-        tm = pipe_lib.StageTimings()
+        trace.start()
         pipe_lib.execute_pipelined(idx, queries, batch_size=32, depth=2,
                                    pool=pool, fuse=fuse,
-                                   plan=plan if fuse else None, timings=tm)
-        per = 1e3 / max(tm.batches, 1)
-        tot = max(tm.stage + tm.assemble + tm.dispatch + tm.block, 1e-9)
-        print(f"# profile {'fused' if fuse else 'unfused'} "
-              f"(per batch of 32): "
-              f"schedule {tm.stage * per:.2f}ms ({tm.stage / tot:.0%}), "
-              f"assemble {tm.assemble * per:.2f}ms "
-              f"({tm.assemble / tot:.0%}), "
-              f"dispatch {tm.dispatch * per:.2f}ms "
-              f"({tm.dispatch / tot:.0%}), "
-              f"device/block {tm.block * per:.2f}ms ({tm.block / tot:.0%})")
+                                   plan=plan if fuse else None)
+        print(f"# profile {'fused' if fuse else 'unfused'} (batches of "
+              f"32): {stage_line(trace.stop())}")
 
 
 def _skewed(quick: bool) -> None:
@@ -877,9 +870,8 @@ def main() -> None:
                          "exceeds MS milliseconds — the JSON artifact is "
                          "still written on failure")
     ap.add_argument("--profile", action="store_true",
-                    help="print the per-batch schedule/assemble/dispatch/"
-                         "device breakdown of the fused resident pipeline "
-                         "and exit")
+                    help="print the schedule/assemble/dispatch/collect "
+                         "totals of the resident pipeline and exit")
     args = ap.parse_args()
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()

@@ -120,6 +120,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import trace
 from repro.core import bitmap as bm
 from repro.core import codecs as codec_lib
 from repro.core import intersect as its
@@ -179,6 +180,7 @@ class _Item:
     pi: int                # index-part ordinal (aggregation order)
     doc_lo: int
     r: object = None                      # (M,) seed: np (host) | jnp (pool)
+    seed_n: int = 0                       # the seed's real length (≤ M)
     folds: list | None = None             # host: J × (N,) np
                                           # pool: J × DecodedSource
     psrc: list | None = None              # Jp × (layout, blk) — layout is
@@ -252,6 +254,112 @@ def _extend_words_dev(row: jnp.ndarray, size: int) -> jnp.ndarray:
         [row, jnp.zeros(size - row.shape[0], jnp.uint32)])
 
 
+def _schedule_item(part, pi: int, qi: int, term_ids: list, codec, cache,
+                   skip: bool, stats: dict | None, pool
+                   ) -> "tuple[GroupKey, _Item] | None":
+    """Resolve one (query, part) work item and its shape signature; None
+    when a term is empty in this part (the part contributes nothing)."""
+    tps = [part.terms[t] for t in term_ids]
+    if any(tp.kind == "empty" for tp in tps):
+        return None
+    pairs = [(t, tp) for t, tp in zip(term_ids, tps)
+             if tp.kind == "list"]
+    pairs.sort(key=lambda p: p[1].n)
+    bm_pairs = [(t, tp) for t, tp in zip(term_ids, tps)
+                if tp.kind == "bitmap"]
+    W = len(bm_pairs[0][1].payload) if bm_pairs else 0
+    bm_words = bm_dev = bm_keys = None
+    if bm_pairs:
+        if pool is not None:
+            # (key, host row) pairs: the arena assembler must not
+            # depend on store residency (tiny pools evict between
+            # schedule and assembly)
+            bm_keys = [(("bm", part.uid, t), np.asarray(tp.payload))
+                       for t, tp in bm_pairs]
+            bm_dev = [pool.stage_bitmap(k, w) for k, w in bm_keys]
+        else:
+            bm_words = np.stack([tp.payload for _, tp in bm_pairs])
+    if not pairs:
+        return (GroupKey("bitmap", 0, 0, W, "-"),
+                _Item(qi, pi, part.doc_lo, bm_words=bm_words, bm_dev=bm_dev,
+                      bm_keys=bm_keys))
+    seed_t, seed_tp = pairs[0]
+    seed = source.resolve(part, seed_t, seed_tp, codec, cache=cache,
+                          r_count=None, stats=stats, pool=pool)
+    seed_np = (seed.vals_np if seed.vals_np is not None
+               else np.asarray(seed.vals))
+    M = seed_np.shape[0]
+    dec, packed = [], []
+    for t, tp in pairs[1:]:
+        src = source.resolve(part, t, tp, codec, cache=cache,
+                             r_count=seed_tp.n, skip=skip,
+                             stats=stats, pool=pool)
+        if isinstance(src, source.PackedSource):
+            packed.append((t, tp, src))
+        else:
+            dec.append(src)
+    psig, psrc = None, None
+    if packed:
+        # stacking along the fold axis needs one block geometry:
+        # keep the longest fold's (block_rows, mode), decode the
+        # rare mismatch (adaptive block sizing on mid-length lists)
+        ref = max(packed, key=lambda p: p[2].n)[2]
+        rows, mode = ref.block_rows, ref.mode
+        keep, demote = [], []
+        for p in packed:
+            same = (p[2].block_rows == rows and p[2].mode == mode)
+            (keep if same else demote).append(p)
+        for t, tp, _ in demote:
+            # cache=None / pool=None: a demoted long list must not
+            # evict the int-budgeted stores' hot short lists — and
+            # staging it resident would permanently win over
+            # want_skip, disabling its block-max skip path over a
+            # one-off grouping accident
+            src = source.resolve(part, t, tp, codec, cache=None,
+                                 skip=False, stats=stats, pool=None)
+            dec.append(src)
+        r_valid = seed_np[: seed.n]
+        cand = [(s, s.candidate_block_ids(r_valid))
+                for _, _, s in keep]
+        k_pad = max(s.self_pads()[0] for s, _ in cand)
+        t_pad = max(s.self_pads()[1] for s, _ in cand)
+        c_pad = max(its.pow2_bucket(len(b), floor=source.CAND_FLOOR)
+                    for _, b in cand)
+        e_max = max(s.num_exceptions for s, _ in cand)
+        e_pad = its.pow2_bucket(e_max, floor=1) if e_max else 0
+        psig = (k_pad, t_pad, c_pad, e_pad, rows, mode)
+        if pool is not None:
+            # keep the PackedSource itself: the arena assembler
+            # materializes its group-padded layout rows on demand
+            # (memoized host-side, one device matrix per operand);
+            # block ids stay raw — the stacker pads them to the
+            # launching key's buckets (fusion may raise them)
+            psrc = [(s, b) for s, b in cand]
+        else:
+            # memoized at the payload's own pads; the stacker
+            # zero-extends into the group slot (no per-group re-pad)
+            psrc = [(source.cached_layout_np(s, s.self_pads(), stats),
+                     b) for s, b in cand]
+        # decoded_ints for packed folds is accounted at LAUNCH
+        # time (the program decodes c_pad blocks per row, and
+        # fusion may raise c_pad past this group's bucket)
+        source._bump(stats, "skip_folds", len(psrc))
+    N = max((s.vals.shape[0] for s in dec), default=128)
+    if pool is not None:
+        r_op = seed.vals
+        folds = dec                          # padded at stack time
+    else:
+        r_op = seed_np
+        folds = [_extend_np(s.vals_np if s.vals_np is not None
+                            else np.asarray(s.vals), N) for s in dec]
+    algo = ("tiled" if N / M <= BATCH_TILED_MAX_RATIO else "gallop")
+    return (GroupKey("svs", M, N, W, algo, psig),
+            _Item(qi, pi, part.doc_lo, r=r_op, seed_n=seed.n,
+                  rsrc=seed if pool is not None else None,
+                  folds=folds, psrc=psrc, bm_words=bm_words, bm_dev=bm_dev,
+                  bm_keys=bm_keys))
+
+
 def schedule(index: HybridIndex, queries: list[list[int]], cache=None,
              skip: bool = True, stats: dict | None = None,
              pool: "source.ResidentPool | None" = None
@@ -271,109 +379,11 @@ def schedule(index: HybridIndex, queries: list[list[int]], cache=None,
     groups: dict[GroupKey, list[_Item]] = defaultdict(list)
     for qi, term_ids in enumerate(queries):
         for pi, part in enumerate(index.parts):
-            pool = pool_of(pi)
-            tps = [part.terms[t] for t in term_ids]
-            if any(tp.kind == "empty" for tp in tps):
-                continue
-            pairs = [(t, tp) for t, tp in zip(term_ids, tps)
-                     if tp.kind == "list"]
-            pairs.sort(key=lambda p: p[1].n)
-            bm_pairs = [(t, tp) for t, tp in zip(term_ids, tps)
-                        if tp.kind == "bitmap"]
-            W = len(bm_pairs[0][1].payload) if bm_pairs else 0
-            bm_words = bm_dev = bm_keys = None
-            if bm_pairs:
-                if pool is not None:
-                    # (key, host row) pairs: the arena assembler must not
-                    # depend on store residency (tiny pools evict between
-                    # schedule and assembly)
-                    bm_keys = [(("bm", part.uid, t), np.asarray(tp.payload))
-                               for t, tp in bm_pairs]
-                    bm_dev = [pool.stage_bitmap(k, w) for k, w in bm_keys]
-                else:
-                    bm_words = np.stack([tp.payload for _, tp in bm_pairs])
-            if not pairs:
-                key = GroupKey("bitmap", 0, 0, W, "-")
-                groups[key].append(_Item(qi, pi, part.doc_lo,
-                                         bm_words=bm_words, bm_dev=bm_dev,
-                                         bm_keys=bm_keys))
-                continue
-            seed_t, seed_tp = pairs[0]
-            seed = source.resolve(part, seed_t, seed_tp, codec, cache=cache,
-                                  r_count=None, stats=stats, pool=pool)
-            seed_np = (seed.vals_np if seed.vals_np is not None
-                       else np.asarray(seed.vals))
-            M = seed_np.shape[0]
-            dec, packed = [], []
-            for t, tp in pairs[1:]:
-                src = source.resolve(part, t, tp, codec, cache=cache,
-                                     r_count=seed_tp.n, skip=skip,
-                                     stats=stats, pool=pool)
-                if isinstance(src, source.PackedSource):
-                    packed.append((t, tp, src))
-                else:
-                    dec.append(src)
-            psig, psrc = None, None
-            if packed:
-                # stacking along the fold axis needs one block geometry:
-                # keep the longest fold's (block_rows, mode), decode the
-                # rare mismatch (adaptive block sizing on mid-length lists)
-                ref = max(packed, key=lambda p: p[2].n)[2]
-                rows, mode = ref.block_rows, ref.mode
-                keep, demote = [], []
-                for p in packed:
-                    same = (p[2].block_rows == rows and p[2].mode == mode)
-                    (keep if same else demote).append(p)
-                for t, tp, _ in demote:
-                    # cache=None / pool=None: a demoted long list must not
-                    # evict the int-budgeted stores' hot short lists — and
-                    # staging it resident would permanently win over
-                    # want_skip, disabling its block-max skip path over a
-                    # one-off grouping accident
-                    src = source.resolve(part, t, tp, codec, cache=None,
-                                         skip=False, stats=stats, pool=None)
-                    dec.append(src)
-                r_valid = seed_np[: seed.n]
-                cand = [(s, s.candidate_block_ids(r_valid))
-                        for _, _, s in keep]
-                k_pad = max(s.self_pads()[0] for s, _ in cand)
-                t_pad = max(s.self_pads()[1] for s, _ in cand)
-                c_pad = max(its.pow2_bucket(len(b), floor=source.CAND_FLOOR)
-                            for _, b in cand)
-                e_max = max(s.num_exceptions for s, _ in cand)
-                e_pad = its.pow2_bucket(e_max, floor=1) if e_max else 0
-                psig = (k_pad, t_pad, c_pad, e_pad, rows, mode)
-                if pool is not None:
-                    # keep the PackedSource itself: the arena assembler
-                    # materializes its group-padded layout rows on demand
-                    # (memoized host-side, one device matrix per operand);
-                    # block ids stay raw — the stacker pads them to the
-                    # launching key's buckets (fusion may raise them)
-                    psrc = [(s, b) for s, b in cand]
-                else:
-                    # memoized at the payload's own pads; the stacker
-                    # zero-extends into the group slot (no per-group re-pad)
-                    psrc = [(source.cached_layout_np(s, s.self_pads(), stats),
-                             b) for s, b in cand]
-                # decoded_ints for packed folds is accounted at LAUNCH
-                # time (the program decodes c_pad blocks per row, and
-                # fusion may raise c_pad past this group's bucket)
-                source._bump(stats, "skip_folds", len(psrc))
-            N = max((s.vals.shape[0] for s in dec), default=128)
-            if pool is not None:
-                r_op = seed.vals
-                folds = dec                          # padded at stack time
-            else:
-                r_op = seed_np
-                folds = [_extend_np(s.vals_np if s.vals_np is not None
-                                    else np.asarray(s.vals), N) for s in dec]
-            algo = ("tiled" if N / M <= BATCH_TILED_MAX_RATIO else "gallop")
-            key = GroupKey("svs", M, N, W, algo, psig)
-            groups[key].append(_Item(qi, pi, part.doc_lo, r=r_op,
-                                     rsrc=seed if pool is not None else None,
-                                     folds=folds, psrc=psrc,
-                                     bm_words=bm_words, bm_dev=bm_dev,
-                                     bm_keys=bm_keys))
+            with trace.span("resolve"):
+                item = _schedule_item(part, pi, qi, term_ids, codec, cache,
+                                      skip, stats, pool_of(pi))
+            if item is not None:
+                groups[item[0]].append(item[1])
     return groups
 
 
@@ -768,22 +778,35 @@ def count_folds(stats: dict | None, items: list, backend: str, r, folds,
     source._bump(stats, "kernel_vmem_fallbacks", dec + pk_n - kernel)
 
 
+def count_probes(stats: dict | None, items: list, jb: int, bp: int,
+                 m: int):
+    """Count the bitmap-probe slots one svs program runs over,
+    ``probe_slots`` = Jb × Bp × M, and of those ``probe_slots_useful``:
+    per real row, its real seed length times its real bitmap count
+    (padded rows, sentinel seed slots and identity bitmaps count 0)."""
+    if stats is None or not jb:
+        return
+    source._bump(stats, "probe_slots", jb * bp * m)
+    source._bump(stats, "probe_slots_useful",
+                 sum(it.seed_n * _n_bitmaps(it)
+                     for it in items if it is not None))
+
+
 def _launch_svs_group(key: GroupKey, items: list[_Item], backend: str,
-                      pool, stats: dict | None, timings=None):
+                      pool, stats: dict | None):
     """Dispatch one svs device program; returns un-materialized device
     results (vals, counts).  The batch dimension is bucketed (sentinel-
     padded rows, results masked back at collect time) so the compile count
-    stays bounded by the signature space.  ``timings`` (a
-    ``pipeline.StageTimings``) splits operand assembly from the async
-    program enqueue."""
+    stays bounded by the signature space."""
     backend = _effective_backend(key, items, backend, stats)
-    t0 = time.perf_counter()
-    R, F, active, pkparts, W, Bp, J, Jb = _assemble_svs(key, items, pool)
-    pk = pk_active = None
-    if pkparts is not None:
-        stacked, PBk, pk_act = pkparts
-        pk = _compose_pk(stacked, PBk)
-        pk_active = jnp.asarray(pk_act)
+    with trace.span("assemble"):
+        R, F, active, pkparts, W, Bp, J, Jb = _assemble_svs(key, items,
+                                                            pool)
+        pk = pk_active = None
+        if pkparts is not None:
+            stacked, PBk, pk_act = pkparts
+            pk = _compose_pk(stacked, PBk)
+            pk_active = jnp.asarray(pk_act)
     mode, rows = "d1", 32
     if key.packed is not None:
         rows, mode = key.packed[4], key.packed[5]
@@ -795,16 +818,12 @@ def _launch_svs_group(key: GroupKey, items: list[_Item], backend: str,
                      sum(len(it.psrc) for it in items)
                      * key.packed[2] * rows * 128)
     count_folds(stats, items, backend, R, F, pk, rows)
+    count_probes(stats, items, Jb, Bp, key.m_bucket)
     if stats is not None:
         stats.setdefault("signatures", set()).add(("svs", key, Bp, J, Jb))
-    t1 = time.perf_counter()
-    out = _svs_program(R, F, jnp.asarray(active), pk, pk_active, W,
-                       key.algo, backend, mode, rows)
-    if timings is not None:
-        t2 = time.perf_counter()
-        timings.assemble += t1 - t0
-        timings.dispatch += t2 - t1
-    return out
+    with trace.span("dispatch"):
+        return _svs_program(R, F, jnp.asarray(active), pk, pk_active, W,
+                            key.algo, backend, mode, rows)
 
 
 def _assemble_bitmap(key: GroupKey, items: list[_Item], pool, *,
@@ -860,18 +879,13 @@ def _assemble_bitmap(key: GroupKey, items: list[_Item], pool, *,
 
 
 def _launch_bitmap_group(key: GroupKey, items: list[_Item], pool,
-                         stats: dict | None, timings=None):
-    t0 = time.perf_counter()
-    words, Bp, J = _assemble_bitmap(key, items, pool)
+                         stats: dict | None):
+    with trace.span("assemble"):
+        words, Bp, J = _assemble_bitmap(key, items, pool)
     if stats is not None:
         stats.setdefault("signatures", set()).add(("bm", key, Bp, J))
-    t1 = time.perf_counter()
-    out = _bitmap_and_program(words)
-    if timings is not None:
-        t2 = time.perf_counter()
-        timings.assemble += t1 - t0
-        timings.dispatch += t2 - t1
-    return out
+    with trace.span("dispatch"):
+        return _bitmap_and_program(words)
 
 
 def _chunk_size(key: GroupKey, items: list[_Item],
@@ -1029,28 +1043,30 @@ def fuse_groups(groups: dict[GroupKey, list[_Item]],
     stickiness widens it: fused decode volume is bounded by the observed
     workload, never by the index size.
     """
-    fused: dict[GroupKey, list[_Item]] = {}
-    for (kind, geom), members in _families(groups).items():
-        items = [it for _, mi in members for it in mi]
-        dims = _family_dims(kind, geom, members)
-        if plan is not None:
-            dims = plan.raised((kind, geom), dims)
-        if kind == "bitmap":
-            w, jb = dims
-            fkey = GroupKey("bitmap", 0, 0, w, "-", fused=(jb,))
-        else:
-            m, n, w, j, jb = dims[:5]
-            packed = (tuple(dims[5:9]) + geom) if geom is not None else None
-            jp = dims[9] if geom is not None else 0
-            fkey = GroupKey("svs", m, n, w, "gallop", packed,
-                            fused=(j, jb, jp))
-        fused[fkey] = items
-    if stats is not None:
-        stats["n_sched_groups"] = (stats.get("n_sched_groups", 0)
-                                   + len(groups))
-        stats["n_fused_groups"] = (stats.get("n_fused_groups", 0)
-                                   + len(fused))
-    return fused
+    with trace.span("fuse"):
+        fused: dict[GroupKey, list[_Item]] = {}
+        for (kind, geom), members in _families(groups).items():
+            items = [it for _, mi in members for it in mi]
+            dims = _family_dims(kind, geom, members)
+            if plan is not None:
+                dims = plan.raised((kind, geom), dims)
+            if kind == "bitmap":
+                w, jb = dims
+                fkey = GroupKey("bitmap", 0, 0, w, "-", fused=(jb,))
+            else:
+                m, n, w, j, jb = dims[:5]
+                packed = ((tuple(dims[5:9]) + geom) if geom is not None
+                          else None)
+                jp = dims[9] if geom is not None else 0
+                fkey = GroupKey("svs", m, n, w, "gallop", packed,
+                                fused=(j, jb, jp))
+            fused[fkey] = items
+        if stats is not None:
+            stats["n_sched_groups"] = (stats.get("n_sched_groups", 0)
+                                       + len(groups))
+            stats["n_fused_groups"] = (stats.get("n_fused_groups", 0)
+                                       + len(fused))
+        return fused
 
 
 def _compile_count() -> int:
@@ -1073,22 +1089,26 @@ def _compile_count() -> int:
 class PendingBatch:
     """Dispatched-but-unmaterialized batch: device result handles per group
     chunk.  JAX async dispatch means the device is (or will be) executing
-    these while the host moves on; ``collect_batch`` blocks on them."""
+    these while the host moves on; ``collect_batch`` blocks on them.
+    ``flush`` is the span handle of the flush that launched it (None while
+    ``trace`` is off), carried to the thread that collects;
+    ``collect_batch`` sets ``d2h_bytes`` to the bytes it copied back."""
     n_queries: int
     max_results: int
     launched: list          # [(key, chunk_items, vals_dev, counts_dev)]
     stats: dict | None
+    flush: object = None
+    d2h_bytes: int = 0
 
 
 def launch_groups(groups: dict[GroupKey, list[_Item]], *, n_queries: int,
                   backend: str = "jax", max_results: int = 1 << 16,
                   max_group_size: int = MAX_GROUP_SIZE,
                   pool: "source.ResidentPool | None" = None,
-                  stats: dict | None = None, timings=None) -> PendingBatch:
+                  stats: dict | None = None) -> PendingBatch:
     """Dispatch one device program per (possibly fused) group chunk without
     materializing any result — the host returns as soon as everything is
-    enqueued.  ``timings`` (a ``pipeline.StageTimings``) attributes operand
-    assembly vs program enqueue wall time."""
+    enqueued."""
     launched = []
     n_dispatches = 0
     c0 = _compile_count() if stats is not None else 0
@@ -1097,11 +1117,10 @@ def launch_groups(groups: dict[GroupKey, list[_Item]], *, n_queries: int,
         for lo in range(0, len(items), step):
             chunk = items[lo: lo + step]
             if key.kind == "bitmap":
-                vals, counts = _launch_bitmap_group(key, chunk, pool, stats,
-                                                    timings)
+                vals, counts = _launch_bitmap_group(key, chunk, pool, stats)
             else:
                 vals, counts = _launch_svs_group(key, chunk, backend, pool,
-                                                 stats, timings)
+                                                 stats)
             launched.append((key, chunk, vals, counts))
             n_dispatches += 1
     accumulate_launch_stats(stats, groups, n_dispatches)
@@ -1115,15 +1134,12 @@ def launch_groups(groups: dict[GroupKey, list[_Item]], *, n_queries: int,
 def accumulate_launch_stats(stats: dict | None, groups, n_dispatches: int):
     """Accumulate per-launch counters (like the decoded_ints/skip_folds
     counters) so one stats dict can span a chunked run of many batches —
-    shared by the single-device and sharded launchers.  ``n_programs``
-    stays an alias of ``n_dispatches`` (the historical name; both count
-    device program launches — distinct *compiled* programs are
-    ``len(stats['signatures'])``)."""
+    shared by the single-device and sharded launchers.  ``n_dispatches``
+    counts device program launches; distinct *compiled* programs are
+    ``len(stats['signatures'])``."""
     if stats is None:
         return
-    for k, v in (("n_groups", len(groups)), ("n_dispatches", n_dispatches),
-                 ("n_programs", n_dispatches),
-                 ("n_items", sum(len(v) for v in groups.values()))):
+    for k, v in (("n_groups", len(groups)), ("n_dispatches", n_dispatches)):
         stats[k] = stats.get(k, 0) + v
 
 
@@ -1131,33 +1147,51 @@ def collect_batch(pending: PendingBatch) -> list[QueryResult]:
     """Materialize a launched batch (blocks on the device) and re-assemble
     per-query results in part order — byte-identical to ``engine.query``.
     svs rows arrive masked-but-uncompacted (valid entries are the
-    non-sentinel slots, still sorted); extraction happens here on host."""
+    non-sentinel slots, still sorted); extraction happens here on host.
+    While ``trace`` records, each chunk first waits for the device in a
+    ``wait`` span of its own, so its ``copy`` span is the D2H copy alone."""
+    with trace.span("collect", parent=pending.flush):
+        return _collect(pending)
+
+
+def _collect(pending: PendingBatch) -> list[QueryResult]:
     per_query: list[list[tuple[int, np.ndarray]]] = \
         [[] for _ in range(pending.n_queries)]
     counts = [0] * pending.n_queries
+    recording = trace.recording()
+    d2h = 0
     for key, chunk, vals_dev, counts_dev in pending.launched:
-        vals = np.asarray(vals_dev)
-        cnts = np.asarray(counts_dev)
-        for b, it in enumerate(chunk):
-            if it is None:          # padded slot (sharded shard-slice pad)
-                continue
-            cnt = int(cnts[b])
-            counts[it.qi] += cnt
-            if not cnt:
-                continue
-            if key.kind == "bitmap":
-                docs = bm.extract_np(vals[b])
-            else:
-                row = vals[b]
-                docs = row[row != its.SENTINEL]
-            per_query[it.qi].append((it.pi, docs.astype(np.int64)
-                                     + it.doc_lo))
+        if recording:
+            with trace.span("wait"):
+                jax.block_until_ready((vals_dev, counts_dev))
+        with trace.span("copy"):
+            vals = np.asarray(vals_dev)
+            cnts = np.asarray(counts_dev)
+        d2h += vals.nbytes + cnts.nbytes
+        with trace.span("extract"):
+            for b, it in enumerate(chunk):
+                if it is None:      # padded slot (sharded shard-slice pad)
+                    continue
+                cnt = int(cnts[b])
+                counts[it.qi] += cnt
+                if not cnt:
+                    continue
+                if key.kind == "bitmap":
+                    docs = bm.extract_np(vals[b])
+                else:
+                    row = vals[b]
+                    docs = row[row != its.SENTINEL]
+                per_query[it.qi].append((it.pi, docs.astype(np.int64)
+                                         + it.doc_lo))
+    pending.d2h_bytes = d2h
     out = []
-    for qi in range(pending.n_queries):
-        chunks = [d for _, d in sorted(per_query[qi], key=lambda x: x[0])]
-        docs = (np.concatenate(chunks) if chunks
-                else np.zeros(0, np.int64))[: pending.max_results]
-        out.append(QueryResult(count=counts[qi], docs=docs))
+    with trace.span("extract"):
+        for qi in range(pending.n_queries):
+            chunks = [d for _, d in sorted(per_query[qi],
+                                           key=lambda x: x[0])]
+            docs = (np.concatenate(chunks) if chunks
+                    else np.zeros(0, np.int64))[: pending.max_results]
+            out.append(QueryResult(count=counts[qi], docs=docs))
     return out
 
 
@@ -1185,9 +1219,9 @@ def execute_batch(index: HybridIndex, queries: list[list[int]], *,
     (pass one per serving session so fused signatures converge; None
     re-derives ceilings per batch).
     stats: optional dict, filled with scheduler counters (n_groups,
-    n_sched_groups/n_fused_groups, n_dispatches, n_compiles, n_items,
-    decoded_ints, skip_folds, resident_hits, layout_hits/misses) for
-    introspection.
+    n_sched_groups/n_fused_groups, n_dispatches, n_compiles,
+    decoded_ints, skip_folds, probe_slots/probe_slots_useful,
+    resident_hits, layout_hits/misses) for introspection.
     """
     assert backend in ("jax", "pallas"), backend
     groups = schedule(index, queries, cache=cache, skip=skip, stats=stats,

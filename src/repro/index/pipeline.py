@@ -23,25 +23,24 @@ fills, layout memo) happens in schedule order, so results are byte-identical
 to ``execute_batch`` run batch by batch — the differential guarantee
 ``tests/test_pipeline.py`` locks in across depths, backends, and corpora.
 
-Per-stage wall time is accounted into ``StageTimings``:
+Each batch is one ``flush`` span of ``repro.trace`` (when it records),
+parent of the batch's ``schedule``, ``launch`` and ``collect`` spans:
 
-    stage     host scheduling: resolve/bucketing + candidate-block search
+    schedule  host scheduling: resolve/bucketing + candidate-block search
               (+ megagroup fusion, which is pure bookkeeping)
-    assemble  operand assembly (arena gathers / host stacking + upload)
-    dispatch  async program enqueue
-    block     time spent blocked on device results at collect
+    assemble  operand assembly (arena gathers / host stacking + upload),
+              inside ``launch``, recorded by the launcher
+    dispatch  async program enqueue, likewise
+    collect   blocked on device results, the D2H copy and extraction
 
 ``serve.py --pipeline N`` and ``bench_engine.py --profile`` report the
-breakdown; ``block`` collapsing toward zero at depth ≥ 2 is the visible
-signature of a hidden device.  The assemble/dispatch split is attributed
-inside the launcher (``batch.launch_groups`` /
-``shard.launch_groups_sharded`` accept the timings object); a custom
-``launch_fn`` that ignores it simply leaves those two fields zero.
+breakdown; ``collect`` collapsing toward the extraction alone at depth ≥ 2
+is the visible signature of a hidden device.
 
 This module is DESIGN.md §2.8 (the pipelined half); the sharded executor
 (DESIGN.md §2.9, ``repro.index.shard``) reuses this exact loop through the
 ``schedule_fn``/``launch_fn`` hooks, fanning each launch across the shard
-devices while in-flight tracking, depth bounding, and stage accounting
+devices while in-flight tracking, depth bounding, and the stage spans
 stay shared.  Invariants callers rely on:
 
   * **Byte-identical to the unpipelined path** — mutations of shared
@@ -57,28 +56,12 @@ stay shared.  Invariants callers rely on:
 
 from __future__ import annotations
 
-import dataclasses
-import time
 from collections import deque
 
+from repro import trace
 from repro.index import batch as batch_lib
 from repro.index.builder import HybridIndex
 from repro.index.engine import QueryResult
-
-
-@dataclasses.dataclass
-class StageTimings:
-    """Cumulative per-stage wall time across a pipelined run."""
-    stage: float = 0.0          # host scheduling (resolve + bucket + fuse)
-    assemble: float = 0.0       # operand assembly (gathers / stack + H2D)
-    dispatch: float = 0.0       # async program enqueue
-    block: float = 0.0          # blocked on device results
-    batches: int = 0
-
-    def as_dict(self) -> dict:
-        return {"stage_s": self.stage, "assemble_s": self.assemble,
-                "dispatch_s": self.dispatch, "block_s": self.block,
-                "batches": self.batches}
 
 
 def execute_pipelined(index: HybridIndex, queries: list[list[int]], *,
@@ -89,7 +72,6 @@ def execute_pipelined(index: HybridIndex, queries: list[list[int]], *,
                       fuse: bool = True,
                       plan: "batch_lib.FusionPlan | None" = None,
                       stats: dict | None = None,
-                      timings: StageTimings | None = None,
                       schedule_fn=None, launch_fn=None
                       ) -> list[QueryResult]:
     """Answer ``queries`` in ``batch_size`` chunks with up to ``depth``
@@ -105,7 +87,7 @@ def execute_pipelined(index: HybridIndex, queries: list[list[int]], *,
     n_queries, stats) -> PendingBatch`` override the two pipeline stages —
     the sharded executor (``repro.index.shard``, DESIGN.md §2.9) plugs in
     per-shard group assembly and fan-out dispatch here while reusing this
-    loop's in-flight tracking and stage accounting unchanged.  Defaults are
+    loop's in-flight tracking and stage spans unchanged.  Defaults are
     the single-device ``batch`` scheduler/launcher."""
     assert depth >= 1, depth
     assert batch_size >= 1, batch_size
@@ -124,25 +106,23 @@ def execute_pipelined(index: HybridIndex, queries: list[list[int]], *,
             return batch_lib.launch_groups(
                 groups, n_queries=n_queries, backend=backend,
                 max_results=max_results, max_group_size=max_group_size,
-                pool=pool, stats=stats, timings=timings)
+                pool=pool, stats=stats)
     inflight: deque[batch_lib.PendingBatch] = deque()
     out: list[QueryResult] = []
 
     def drain_one():
-        t0 = time.perf_counter()
-        out.extend(batch_lib.collect_batch(inflight.popleft()))
-        if timings is not None:
-            timings.block += time.perf_counter() - t0
+        pending = inflight.popleft()
+        out.extend(batch_lib.collect_batch(pending))
+        trace.end(pending.flush)
 
     for lo in range(0, len(queries), batch_size):
         chunk = queries[lo: lo + batch_size]
-        t0 = time.perf_counter()
-        groups = schedule_fn(chunk, stats)
-        t1 = time.perf_counter()
-        pending = launch_fn(groups, len(chunk), stats)
-        if timings is not None:
-            timings.stage += t1 - t0
-            timings.batches += 1
+        flush = trace.flush()
+        with trace.span("schedule", parent=flush):
+            groups = schedule_fn(chunk, stats)
+        with trace.span("launch", parent=flush):
+            pending = launch_fn(groups, len(chunk), stats)
+        pending.flush = flush
         inflight.append(pending)
         while len(inflight) >= depth:
             drain_one()

@@ -39,13 +39,13 @@ Execution model — shard along the batch axis, not the program:
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import trace
 from repro.index import batch as batch_lib
 from repro.index import pipeline as pipe_lib
 from repro.index import source
@@ -258,9 +258,11 @@ def _launch_svs_sharded(sharded: ShardedIndex, key, per_shard: list,
     backend = batch_lib._effective_backend(key, all_items, backend, stats,
                                            bp=S * Bq)
     batch_lib.count_folds(stats, all_items, backend, R, F, pk, rows)
-    vals, counts = batch_lib._svs_program(
-        R, F, active, pk, pk_active, W, key.algo, backend, mode, rows,
-        mesh=sharded.mesh if len(sharded.devices) > 1 else None)
+    batch_lib.count_probes(stats, all_items, Jb, S * Bq, key.m_bucket)
+    with trace.span("dispatch"):
+        vals, counts = batch_lib._svs_program(
+            R, F, active, pk, pk_active, W, key.algo, backend, mode, rows,
+            mesh=sharded.mesh if len(sharded.devices) > 1 else None)
     return _flat_items(per_shard, Bq), vals, counts
 
 
@@ -278,14 +280,15 @@ def _launch_bitmap_sharded(sharded: ShardedIndex, key, per_shard: list,
     if stats is not None:
         stats.setdefault("signatures", set()).add(
             ("bm-sharded", key, S, Bq, J))
-    vals, counts = batch_lib._bitmap_and_program(words)
+    with trace.span("dispatch"):
+        vals, counts = batch_lib._bitmap_and_program(words)
     return _flat_items(per_shard, Bq), vals, counts
 
 
 def launch_groups_sharded(sharded: ShardedIndex, groups, *, n_queries: int,
                           backend: str = "jax", max_results: int = 1 << 16,
                           max_group_size: int = batch_lib.MAX_GROUP_SIZE,
-                          stats: dict | None = None, timings=None
+                          stats: dict | None = None
                           ) -> batch_lib.PendingBatch:
     """Dispatch every group chunk as one SPMD program across the shard
     devices, without materializing results (the fan-out half; the existing
@@ -293,8 +296,8 @@ def launch_groups_sharded(sharded: ShardedIndex, groups, *, n_queries: int,
     order per-query results exactly as the single-device engine does).
     With fused megagroups the per-batch dispatch collapse multiplies by
     the shard count: one program per family covers *all* shards' rows.
-    ``timings`` splits per-shard assembly + glue from the program enqueue
-    (same contract as ``batch.launch_groups``)."""
+    Each chunk's ``assemble`` span holds its ``dispatch`` span: assembly
+    and glue are its self time."""
     launched = []
     n_dispatches = 0
     c0 = batch_lib._compile_count() if stats is not None else 0
@@ -308,18 +311,13 @@ def launch_groups_sharded(sharded: ShardedIndex, groups, *, n_queries: int,
         width = max(len(sub) for sub in per)
         for lo in range(0, max(width, 1), step):
             sub = [s[lo: lo + step] for s in per]
-            t0 = time.perf_counter()
-            if key.kind == "bitmap":
-                flat, vals, counts = _launch_bitmap_sharded(
-                    sharded, key, sub, stats)
-            else:
-                flat, vals, counts = _launch_svs_sharded(
-                    sharded, key, sub, backend, stats)
-            if timings is not None:
-                # the sharded launchers interleave assembly and the single
-                # program call; attribute the whole span to assemble+glue
-                # and let `block` absorb device time, as §2.9 documents
-                timings.assemble += time.perf_counter() - t0
+            with trace.span("assemble"):
+                if key.kind == "bitmap":
+                    flat, vals, counts = _launch_bitmap_sharded(
+                        sharded, key, sub, stats)
+                else:
+                    flat, vals, counts = _launch_svs_sharded(
+                        sharded, key, sub, backend, stats)
             launched.append((key, flat, vals, counts))
             n_dispatches += 1
     batch_lib.accumulate_launch_stats(stats, groups, n_dispatches)
@@ -337,8 +335,7 @@ def execute_sharded(sharded: ShardedIndex, queries: list, *,
                     max_group_size: int = batch_lib.MAX_GROUP_SIZE,
                     fuse: bool = True,
                     plan: "batch_lib.FusionPlan | None" = None,
-                    stats: dict | None = None,
-                    timings: "pipe_lib.StageTimings | None" = None
+                    stats: dict | None = None
                     ) -> list[QueryResult]:
     """Answer ``queries`` against the sharded index, pipelined at ``depth``
     (DESIGN.md §2.9): every batch fans out to all shards in one dispatch
@@ -362,9 +359,9 @@ def execute_sharded(sharded: ShardedIndex, queries: list, *,
         return launch_groups_sharded(
             sharded, groups, n_queries=n_queries, backend=backend,
             max_results=max_results, max_group_size=max_group_size,
-            stats=stats, timings=timings)
+            stats=stats)
 
     return pipe_lib.execute_pipelined(
         sharded.index, queries, batch_size=batch_size, depth=depth,
-        max_results=max_results, stats=stats, timings=timings,
+        max_results=max_results, stats=stats,
         schedule_fn=schedule_fn, launch_fn=launch_fn)

@@ -32,6 +32,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import trace
 from repro.configs.base import get_config
 
 
@@ -150,6 +151,24 @@ def _print_codec_stats(args, idx) -> None:
           f"({st['bits_per_int']:.2f} bits/int) [{counts}]")
 
 
+def stage_line(spans: list) -> str:
+    """The four stage totals of a recorded pipelined run — schedule (and
+    the fusion inside it), operand assembly (self time), dispatch,
+    collect — in ms and as shares of their sum, over its flushes
+    (``repro.trace`` spans)."""
+    tot = trace.totals_ns(spans)
+    stages = {"schedule": tot.get("schedule", 0),
+              "assemble": trace.self_totals_ns(spans).get("assemble", 0),
+              "dispatch": tot.get("dispatch", 0),
+              "collect": tot.get("collect", 0)}
+    whole = max(sum(stages.values()), 1)
+    parts = [f"{k} {v * 1e-6:.1f} ms ({v / whole:.0%})"
+             for k, v in stages.items()]
+    parts[0] += f" of which fuse {tot.get('fuse', 0) * 1e-6:.1f} ms"
+    return (", ".join(parts)
+            + f" over {sum(s.name == 'flush' for s in spans)} batches")
+
+
 def serve_index(args):
     from repro.index import builder, corpus as corpus_lib, engine, source
     for w in coerce_index_flags(args):
@@ -199,14 +218,13 @@ def serve_index(args):
         depth = args.pipeline
         plan = batch_lib.FusionPlan() if args.fuse else None
 
-        def run_all(stats=None, timings=None):
+        def run_all(stats=None):
             stats = {} if stats is None else stats
             if depth:
                 out = pipe_lib.execute_pipelined(
                     idx, queries, batch_size=args.batch, depth=depth,
                     backend=args.backend, cache=cache, pool=pool,
-                    fuse=args.fuse, plan=plan, stats=stats,
-                    timings=timings)
+                    fuse=args.fuse, plan=plan, stats=stats)
             else:
                 out = []
                 for lo in range(0, len(queries), args.batch):
@@ -244,10 +262,12 @@ def serve_index(args):
                       f"max_passes ({passes} passes, {n_sigs} signatures) "
                       f"without converging — the timed run may pay hidden "
                       f"compiles")
-        timings = pipe_lib.StageTimings() if depth else None
+        if depth:
+            trace.start()
         t0 = time.perf_counter()
-        results, stats = run_all(timings=timings)
+        results, stats = run_all()
         dt = time.perf_counter() - t0
+        spans = trace.stop()
         hits = sum(r.count for r in results)
         mode = (f"--pipeline {depth} (batch {args.batch})" if depth
                 else f"--batch {args.batch}")
@@ -266,19 +286,9 @@ def serve_index(args):
               f"{stats.get('resident_hits', 0)} resident hits), "
               f"{idx.stats()['bits_per_int']:.2f} bits/int"
               f"{cache_note()}")
-        if timings is not None:
-            tot = max(timings.stage + timings.assemble + timings.dispatch
-                      + timings.block, 1e-9)
+        if depth:
             print(f"[serve]   pipeline depth {depth}: "
-                  f"stage {timings.stage * 1e3:.1f} ms "
-                  f"({timings.stage / tot:.0%}), "
-                  f"assemble {timings.assemble * 1e3:.1f} ms "
-                  f"({timings.assemble / tot:.0%}), "
-                  f"dispatch {timings.dispatch * 1e3:.1f} ms "
-                  f"({timings.dispatch / tot:.0%}), "
-                  f"block {timings.block * 1e3:.1f} ms "
-                  f"({timings.block / tot:.0%}) "
-                  f"over {timings.batches} batches")
+                  f"{stage_line(spans)}")
         return
     # warm / compile every signature; two passes when residency (cache or
     # pool) changes how terms resolve — steady state, not first-touch
@@ -561,7 +571,7 @@ def serve_index_sharded(args, corpus):
     Run under XLA_FLAGS=--xla_force_host_platform_device_count=N to get N
     host-platform devices on CPU-only machines (must be set before jax
     initializes; with fewer devices, shards share them contiguously)."""
-    from repro.index import builder, pipeline as pipe_lib, shard as shard_lib
+    from repro.index import builder, shard as shard_lib
     t0 = time.perf_counter()
     sharded = builder.build_sharded(
         corpus.postings, corpus.n_docs, n_shards=args.shards,
@@ -582,11 +592,11 @@ def serve_index_sharded(args, corpus):
     from repro.index import batch as batch_lib
     plan = batch_lib.FusionPlan() if args.fuse else None
 
-    def run_all(stats=None, timings=None):
+    def run_all(stats=None):
         return shard_lib.execute_sharded(
             sharded, queries, batch_size=batch, depth=depth,
             backend=args.backend, fuse=args.fuse, plan=plan,
-            stats=stats, timings=timings)
+            stats=stats)
 
     # warm to signature fixed point (same rationale as the batched path);
     # with --warmup the compile accounting of the pass is reported
@@ -602,11 +612,12 @@ def serve_index_sharded(args, corpus):
         print(f"[serve] warning: signature warm loop stopped at max_passes "
               f"({passes} passes, {n_sigs} signatures) without converging "
               f"— the timed run may pay hidden compiles")
-    timings = pipe_lib.StageTimings()
     stats: dict = {}
+    trace.start()
     t0 = time.perf_counter()
-    results = run_all(stats=stats, timings=timings)
+    results = run_all(stats=stats)
     dt = time.perf_counter() - t0
+    spans = trace.stop()
     hits = sum(r.count for r in results)
     n_batches = max((len(queries) + batch - 1) // batch, 1)
     print(f"[serve] paper-index --shards {args.shards} "
@@ -618,15 +629,7 @@ def serve_index_sharded(args, corpus):
           f"({stats.get('n_dispatches', 0) / n_batches:.1f}/batch, "
           f"{len(stats.get('signatures', ()))} programs, "
           f"{stats.get('n_compiles', 0)} compiles)")
-    tot = max(timings.stage + timings.assemble + timings.dispatch
-              + timings.block, 1e-9)
-    print(f"[serve]   stage {timings.stage * 1e3:.1f} ms "
-          f"({timings.stage / tot:.0%}), "
-          f"assemble {timings.assemble * 1e3:.1f} ms "
-          f"({timings.assemble / tot:.0%}), "
-          f"dispatch {timings.dispatch * 1e3:.1f} ms "
-          f"({timings.dispatch / tot:.0%}), "
-          f"block {timings.block * 1e3:.1f} ms ({timings.block / tot:.0%})")
+    print(f"[serve]   {stage_line(spans)}")
     return results
 
 

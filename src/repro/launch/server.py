@@ -55,6 +55,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro import trace
 from repro.index import batch as batch_lib
 from repro.launch import faults as faults_lib
 
@@ -72,7 +73,8 @@ class Request:
     report is built from (arrive -> admit -> done).  ``outcome`` is the
     resolution contract: every admitted request ends in exactly one of
     ``done`` / ``timeout`` / ``error`` with its ``done`` event set (shed
-    arrivals never become a Request at all)."""
+    arrivals never become a Request at all).  ``flush`` is the id of the
+    flush span that carried it while ``repro.trace`` records, else -1."""
     rid: int
     terms: list
     t_arrive: float
@@ -80,6 +82,7 @@ class Request:
     t_done: float = 0.0
     result: object = None
     outcome: str = "pending"
+    flush: int = -1
     done: asyncio.Event = field(default_factory=asyncio.Event)
 
     @property
@@ -515,8 +518,11 @@ class ContinuousBatchingServer:
             if not reqs:
                 sem.release()
                 return
+        fl = trace.flush()
         for r in reqs:
             r.t_admit = now
+            if fl is not None:
+                r.flush = fl.id
         m.n_flushes += 1
         if reason == "full":
             m.flush_full += 1
@@ -535,11 +541,13 @@ class ContinuousBatchingServer:
                 if self.injector is not None:
                     self.injector.fire("launch")
                 snap = self._snapshot()
-                groups = self._schedule([r.terms for r in reqs], self.stats,
-                                        account=account, snap=snap,
-                                        fuse=fuse)
-                pending = self._launch(groups, len(reqs), self.stats,
-                                       snap=snap, backend=backend)
+                with trace.span("schedule", parent=fl):
+                    groups = self._schedule([r.terms for r in reqs],
+                                            self.stats, account=account,
+                                            snap=snap, fuse=fuse)
+                with trace.span("launch", parent=fl):
+                    pending = self._launch(groups, len(reqs), self.stats,
+                                           snap=snap, backend=backend)
                 break
             except faults_lib.TransientFault:
                 # bounded retry with exponential backoff; repeated
@@ -550,6 +558,7 @@ class ContinuousBatchingServer:
                 self.ladder.on_failure()
                 if attempt >= self.max_retries:
                     self._resolve_error(reqs)
+                    trace.end(fl)
                     sem.release()
                     return
                 attempt += 1
@@ -563,8 +572,11 @@ class ContinuousBatchingServer:
                 m.n_faults += 1
                 self.ladder.on_failure()
                 self._resolve_error(reqs)
+                trace.end(fl)
                 sem.release()
                 return
+
+        pending.flush = fl
 
         def collect():
             if self.injector is not None:
@@ -590,11 +602,16 @@ class ContinuousBatchingServer:
                 err = e
             finally:
                 sem.release()
+                trace.end(fl)
             if err is not None:
                 m.n_faults += 1
                 self.ladder.on_failure()
                 self._resolve_error(reqs)
                 return
+            # collect_batch ran on the collector thread; stats is only
+            # written here, on the event loop
+            self.stats["d2h_bytes"] = (self.stats.get("d2h_bytes", 0)
+                                       + pending.d2h_bytes)
             self.ladder.on_success()
             for r in reqs:
                 r.outcome = "done"

@@ -121,7 +121,7 @@ def test_batched_matches_sequential(small_corpus, codec, B, n_parts):
     stats = {}
     batched = batch_lib.execute_batch(idx, small_corpus.queries, stats=stats)
     assert len(batched) == len(small_corpus.queries)
-    assert stats["n_items"] > 0
+    assert stats["n_dispatches"] > 0
     for q, br in zip(small_corpus.queries, batched):
         sr = engine.query(idx, q)
         assert sr.count == br.count
@@ -154,14 +154,20 @@ def test_batched_with_cache_matches(small_corpus):
     assert len(cache._store) > 0
 
 
+def _n_items(idx, queries) -> int:
+    """The (query, part) work items the scheduler makes of ``queries``."""
+    return sum(len(v) for v in batch_lib.schedule(idx, queries).values())
+
+
 def test_batched_grouping_amortizes_programs(small_corpus):
     """The scheduler must fuse work: device programs < work items."""
     idx = builder.build(small_corpus.postings, small_corpus.n_docs,
                         codec_name="fastpfor-d1", B=16, n_parts=2)
     stats = {}
     batch_lib.execute_batch(idx, small_corpus.queries, stats=stats)
-    assert stats["n_programs"] <= stats["n_items"]
-    assert stats["n_programs"] == stats["n_groups"]  # no chunk overflow here
+    n_items = _n_items(idx, small_corpus.queries)
+    assert stats["n_dispatches"] <= n_items
+    assert stats["n_dispatches"] == stats["n_groups"]  # no chunk overflow
 
 
 def test_batched_respects_max_group_size(small_corpus):
@@ -170,7 +176,7 @@ def test_batched_respects_max_group_size(small_corpus):
     stats = {}
     batched = batch_lib.execute_batch(idx, small_corpus.queries,
                                       max_group_size=1, stats=stats)
-    assert stats["n_programs"] == stats["n_items"]
+    assert stats["n_dispatches"] == _n_items(idx, small_corpus.queries)
     for q, br in zip(small_corpus.queries, batched):
         sr = engine.query(idx, q)
         assert sr.count == br.count

@@ -14,9 +14,11 @@ Layers:
 import numpy as np
 import pytest
 
+from repro import trace
 from repro.index import batch as batch_lib
 from repro.index import pipeline as pipe_lib
 from repro.index import builder, corpus as corpus_lib, engine, source
+from repro.launch import serve as serve_lib
 
 pytestmark = pytest.mark.pipeline
 
@@ -151,16 +153,33 @@ def test_pipeline_depth_one_equals_execute_batch(uniform):
 
 
 def test_pipeline_timings_populated(uniform):
+    """The recorder's totals of a pipelined run: one flush per batch, each
+    with its schedule, launch and collect, the launcher's assemble and
+    dispatch spans under launch, all four stage totals above 0."""
     idx, queries, seq = uniform
-    tm = pipe_lib.StageTimings()
-    out = pipe_lib.execute_pipelined(idx, queries, batch_size=4, depth=2,
-                                     timings=tm)
+    trace.start()
+    try:
+        out = pipe_lib.execute_pipelined(idx, queries, batch_size=4,
+                                         depth=2)
+    finally:
+        spans = trace.stop()
     _assert_identical(out, seq)
-    assert tm.batches == (len(queries) + 3) // 4
-    assert tm.stage >= 0 and tm.dispatch >= 0 and tm.block >= 0
-    assert tm.assemble > 0          # launcher-attributed operand assembly
-    assert set(tm.as_dict()) == {"stage_s", "assemble_s", "dispatch_s",
-                                 "block_s", "batches"}
+    n_batches = (len(queries) + 3) // 4
+    by_id = {s.id: s for s in spans}
+    flushes = [s for s in spans if s.name == "flush"]
+    assert len(flushes) == n_batches
+    for name in ("schedule", "launch", "collect"):
+        stage = [s for s in spans if s.name == name]
+        assert sorted(by_id[s.parent].id for s in stage) == \
+            sorted(f.id for f in flushes)
+    for s in spans:
+        if s.name in ("assemble", "dispatch"):
+            assert by_id[s.parent].name == "launch"
+    tot = trace.totals_ns(spans)
+    assert all(tot[k] > 0 for k in ("schedule", "assemble", "dispatch",
+                                    "collect"))
+    line = serve_lib.stage_line(spans)
+    assert "assemble" in line and "of which fuse" in line
 
 
 # --------------------------------------------------------------------------
