@@ -46,6 +46,9 @@ query *batches* inside the vectorized regime:
      each step a skip-aware partial decode of candidate blocks only), then
      the surviving candidates are probed against the stacked bitmap terms
      (J_b, B, W) — candidates never round-trip to host between terms.
+     The probe runs only over each bitmap slot's real seed extent: chunks
+     of ``PROBE_CHUNK`` seed slots up to the longest real seed among the
+     rows that have that slot (``probe_chunks``).
      Every step ANDs its match mask into one running validity mask over the
      *original* sorted seed buffer instead of compacting between folds:
      compaction never shrank the (static) shapes, but its cumsum+scatter
@@ -132,6 +135,7 @@ MAX_GROUP_SIZE = 128          # hard cap on items per device program
 GROUP_INT_BUDGET = 1 << 25    # cap operand ints per program: B·(J·N+M+J_b·W)
 BATCH_TILED_MAX_RATIO = 4.0   # vmapped tile-merge loses early exit; see above
 PALLAS_MIN_OCCUPANCY = 0.5    # interpret-mode kernel guard; see below
+PROBE_CHUNK = 1 << 16         # seed slots per bitmap-probe step; see below
 
 # Interpret-mode Pallas executes every grid step on the host, so its cost
 # scales with the PADDED grid (Bp·(1+J+Jp) fused-family ceiling slots), not
@@ -144,6 +148,15 @@ PALLAS_MIN_OCCUPANCY = 0.5    # interpret-mode kernel guard; see below
 # counted in stats["pallas_lowocc_fallbacks"].  Compiled mode skips the
 # guard: dead TPU grid steps retire in microseconds and kernel residency
 # is worth keeping (DESIGN.md §2.12).
+
+# Bitmap probes gather one word per seed slot, so a probe over the whole
+# (Bp, M) seed stack at the family ceiling is mostly padding: sentinel tails
+# past each row's real seed, and all-ones identity rows of rows with fewer
+# bitmaps than Jb.  The program probes bitmap slot j over the first
+# ``probe_chunks(...)[j]`` chunks of PROBE_CHUNK seed slots only — a traced
+# trip count, so the compiled signatures stay those of the shapes.  At Bp =
+# 2 a chunk is ~1.5 ms of gather on a v5e against a loop step of a few µs,
+# and rounding up wastes at most one chunk per row.
 
 # Donating the candidate buffer lets XLA alias its pages for the output; it
 # is always freshly stacked per dispatch so nothing aliases it on the host.
@@ -425,10 +438,11 @@ def _row_split(fn, mesh, *fold_args):
 
 
 @partial(jax.jit, static_argnames=("algo", "backend", "mode", "block_rows",
-                                   "mesh"),
+                                   "probe_chunk", "mesh"),
          donate_argnums=_DONATE_CANDIDATES)
-def _svs_program(r, folds, fold_active, pk, pk_active, words, algo: str,
-                 backend: str, mode: str, block_rows: int, mesh=None):
+def _svs_program(r, folds, fold_active, pk, pk_active, words, probe_n,
+                 algo: str, backend: str, mode: str, block_rows: int,
+                 probe_chunk: int, mesh=None):
     """One device program per group chunk: decoded folds → packed folds →
     bitmap probes, candidates staying on device throughout.  Every stage
     computes a match mask over the original sorted seed buffer ``r`` and
@@ -436,10 +450,13 @@ def _svs_program(r, folds, fold_active, pk, pk_active, words, algo: str,
     invalid slots set to SENTINEL — per-row sorted but NOT compacted (the
     host extracts the valid prefix-by-mask at collect).  ``pk`` is the
     tuple of stacked batch-uniform packed operands (or None); ``words`` the
-    stacked bitmap rows (or None).  ``r`` is donated (see module
-    docstring).  ``mesh`` is the sharded executor's ('data',) mesh when
-    the batch rows are split across devices (None on one device): the
-    pallas megakernels then run per device on its rows."""
+    stacked bitmap rows (or None) and ``probe_n`` its (Jb,) int32 chunk
+    counts from ``probe_chunks``: bitmap slot j probes the seed slots
+    [0, probe_n[j] · probe_chunk) of every row (None with ``words``).
+    ``r`` is donated (see module docstring).  ``mesh`` is the sharded
+    executor's ('data',) mesh when the batch rows are split across devices
+    (None on one device): the pallas megakernels then run per device on
+    its rows."""
     valid = r != its.SENTINEL
     if folds.shape[0]:
         if backend == "pallas":
@@ -473,10 +490,20 @@ def _svs_program(r, folds, fold_active, pk, pk_active, words, algo: str,
                 lambda rr, op: its.intersect_packed_batch(
                     rr, *op, mode=mode, block_rows=block_rows))
     if words is not None:
-        def wstep(v, w):
-            return jax.vmap(bm.probe)(w, r, v), None
+        C = probe_chunk
 
-        valid, _ = lax.scan(wstep, valid, words)
+        def wstep(v, xs):
+            w, n = xs
+
+            def chunk(c, v):
+                rc = lax.dynamic_slice_in_dim(r, c * C, C, axis=1)
+                vc = lax.dynamic_slice_in_dim(v, c * C, C, axis=1)
+                return lax.dynamic_update_slice_in_dim(
+                    v, jax.vmap(bm.probe)(w, rc, vc), c * C, axis=1)
+
+            return lax.fori_loop(0, n, chunk, v), None
+
+        valid, _ = lax.scan(wstep, valid, (words, probe_n))
     return (jnp.where(valid, r, its.SENTINEL),
             jnp.sum(valid.astype(jnp.int32), axis=-1))
 
@@ -778,15 +805,34 @@ def count_folds(stats: dict | None, items: list, backend: str, r, folds,
     source._bump(stats, "kernel_vmem_fallbacks", dec + pk_n - kernel)
 
 
-def count_probes(stats: dict | None, items: list, jb: int, bp: int,
-                 m: int):
-    """Count the bitmap-probe slots one svs program runs over,
-    ``probe_slots`` = Jb × Bp × M, and of those ``probe_slots_useful``:
-    per real row, its real seed length times its real bitmap count
-    (padded rows, sentinel seed slots and identity bitmaps count 0)."""
-    if stats is None or not jb:
+def probe_chunks(items: list, jb: int, m: int) -> tuple[np.ndarray, int]:
+    """The bitmap probe's extents for one svs program: chunk size C =
+    min(M, PROBE_CHUNK) and, per bitmap slot j < Jb, ceil(max seed_n / C)
+    over the real rows with more than j bitmaps (0 when none has a j-th).
+    Every slot skipped is one the probe cannot change: seed slots at or
+    past a row's ``seed_n`` are SENTINEL (already invalid), and a row's
+    bitmap slots past its own count are identity rows.  ``items`` may hold
+    None (sharded per-shard padding)."""
+    c = min(m, PROBE_CHUNK)
+    ext = np.zeros(jb, np.int64)
+    for it in items:
+        if it is not None:
+            nb = min(_n_bitmaps(it), jb)
+            ext[:nb] = np.maximum(ext[:nb], it.seed_n)
+    return (-(-ext // c)).astype(np.int32), c
+
+
+def count_probes(stats: dict | None, items: list, chunks: np.ndarray,
+                 c: int, bp: int):
+    """Count the bitmap-probe slots one svs program gathers,
+    ``probe_slots`` = Σ_j chunks[j] × C × Bp (the same ``probe_chunks``
+    extents the program runs over, not Jb × Bp × M), and of those
+    ``probe_slots_useful``: per real row, its real seed length times its
+    real bitmap count (padded rows, sentinel seed slots and identity
+    bitmaps count 0)."""
+    if stats is None or not len(chunks):
         return
-    source._bump(stats, "probe_slots", jb * bp * m)
+    source._bump(stats, "probe_slots", int(chunks.sum()) * c * bp)
     source._bump(stats, "probe_slots_useful",
                  sum(it.seed_n * _n_bitmaps(it)
                      for it in items if it is not None))
@@ -818,12 +864,14 @@ def _launch_svs_group(key: GroupKey, items: list[_Item], backend: str,
                      sum(len(it.psrc) for it in items)
                      * key.packed[2] * rows * 128)
     count_folds(stats, items, backend, R, F, pk, rows)
-    count_probes(stats, items, Jb, Bp, key.m_bucket)
+    chunks, c = probe_chunks(items, Jb, key.m_bucket)
+    count_probes(stats, items, chunks, c, Bp)
     if stats is not None:
         stats.setdefault("signatures", set()).add(("svs", key, Bp, J, Jb))
     with trace.span("dispatch"):
         return _svs_program(R, F, jnp.asarray(active), pk, pk_active, W,
-                            key.algo, backend, mode, rows)
+                            None if W is None else jnp.asarray(chunks),
+                            key.algo, backend, mode, rows, probe_chunk=c)
 
 
 def _assemble_bitmap(key: GroupKey, items: list[_Item], pool, *,

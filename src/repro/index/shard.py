@@ -182,9 +182,10 @@ def _glue(sharded: ShardedIndex, slices: list, axis: int):
         tuple(shape), sharding, dev_slices)
 
 
-def _put_host(sharded: ShardedIndex, arr: np.ndarray, axis: int):
+def _put_host(sharded: ShardedIndex, arr: np.ndarray, axis: int | None):
     """Upload one host-side operand (active masks, candidate block ids)
-    sharded along ``axis`` — each device receives only its slice."""
+    sharded along ``axis`` — each device receives only its slice; None
+    replicates it (the probe extents)."""
     if len(sharded.devices) == 1:
         return jnp.asarray(arr)
     sharding = NamedSharding(sharded.mesh, _spec(arr.ndim, axis))
@@ -258,10 +259,16 @@ def _launch_svs_sharded(sharded: ShardedIndex, key, per_shard: list,
     backend = batch_lib._effective_backend(key, all_items, backend, stats,
                                            bp=S * Bq)
     batch_lib.count_folds(stats, all_items, backend, R, F, pk, rows)
-    batch_lib.count_probes(stats, all_items, Jb, S * Bq, key.m_bucket)
+    # one extent per bitmap slot over every shard's rows, replicated: each
+    # device loops as far as the longest seed anywhere, with no reduction
+    # across rows on the device
+    chunks, c = batch_lib.probe_chunks(all_items, Jb, key.m_bucket)
+    batch_lib.count_probes(stats, all_items, chunks, c, S * Bq)
+    probe_n = _put_host(sharded, chunks, axis=None) if Jb else None
     with trace.span("dispatch"):
         vals, counts = batch_lib._svs_program(
-            R, F, active, pk, pk_active, W, key.algo, backend, mode, rows,
+            R, F, active, pk, pk_active, W, probe_n, key.algo, backend,
+            mode, rows, probe_chunk=c,
             mesh=sharded.mesh if len(sharded.devices) > 1 else None)
     return _flat_items(per_shard, Bq), vals, counts
 

@@ -109,20 +109,25 @@ def test_engine_matches_bruteforce_random_seeds(seed):
 # batched vs sequential equivalence
 # --------------------------------------------------------------------------
 
-@pytest.mark.parametrize("codec,B,n_parts", [
-    ("bp-d1", 0, 1),
-    ("fastpfor-d1", 16, 2),
-    ("fastpfor-d1", 64, 3),     # includes all-bitmap groups
-    ("varint", 32, 3),
+@pytest.mark.parametrize("corpus,codec,B,n_parts", [
+    pytest.param("small_corpus", "bp-d1", 0, 1, id="bp-d1-0-1"),
+    pytest.param("small_corpus", "fastpfor-d1", 16, 2, id="fastpfor-d1-16-2"),
+    # includes all-bitmap groups
+    pytest.param("small_corpus", "fastpfor-d1", 64, 3, id="fastpfor-d1-64-3"),
+    pytest.param("small_corpus", "varint", 32, 3, id="varint-32-3"),
+    # seeds of 0, 1, C - 1, C, C + 1 and M slots across the bitmap probe's
+    # chunk edges (conftest), with 0 to 4 bitmaps a row
+    pytest.param("probe_edges", "fastpfor-d1", 8, 2, id="probe-edges"),
 ])
-def test_batched_matches_sequential(small_corpus, codec, B, n_parts):
-    idx = builder.build(small_corpus.postings, small_corpus.n_docs,
+def test_batched_matches_sequential(request, corpus, codec, B, n_parts):
+    corpus = request.getfixturevalue(corpus)
+    idx = builder.build(corpus.postings, corpus.n_docs,
                         codec_name=codec, B=B, n_parts=n_parts)
     stats = {}
-    batched = batch_lib.execute_batch(idx, small_corpus.queries, stats=stats)
-    assert len(batched) == len(small_corpus.queries)
+    batched = batch_lib.execute_batch(idx, corpus.queries, stats=stats)
+    assert len(batched) == len(corpus.queries)
     assert stats["n_dispatches"] > 0
-    for q, br in zip(small_corpus.queries, batched):
+    for q, br in zip(corpus.queries, batched):
         sr = engine.query(idx, q)
         assert sr.count == br.count
         assert br.docs.dtype == sr.docs.dtype

@@ -4,6 +4,10 @@ Layers:
   * fused == unfused == sequential byte-identity over {jax, pallas} ×
     {uniform, skewed} corpora, single-device and sharded at {1, 2, 4},
   * fusion edge cases: single-group batch, all-bitmap family, empty batch,
+  * the bitmap probe's chunk extents: seeds of 0, 1, C - 1, C, C + 1 and M
+    slots with 0 to Jb bitmaps a row and padded rows, through host
+    stacking, pool arena, pool stacking, fused and sharded programs; every
+    assembled seed row holds exactly ``seed_n`` non-sentinel slots,
   * the dispatch collapse itself (scheduled signatures ≫ fused dispatches
     on a mixed batch) and FusionPlan stickiness,
   * ``warmup`` compile accounting: steady-state serving after warmup
@@ -13,6 +17,7 @@ Layers:
 import numpy as np
 import pytest
 
+from repro.core import intersect as its
 from repro.index import batch as batch_lib
 from repro.index import builder, corpus as corpus_lib, engine, source
 from repro.index import pipeline as pipe_lib
@@ -62,6 +67,33 @@ def mixed():
     return idx, corpus.queries, seq
 
 
+@pytest.fixture
+def edges(probe_edges):
+    """``probe_edge_corpus`` (conftest) built, with the probe's chunk cut
+    small for the test."""
+    idx = builder.build(probe_edges.postings, probe_edges.n_docs,
+                        codec_name="fastpfor-d1", B=8, n_parts=2)
+    seq = [engine.query(idx, q) for q in probe_edges.queries]
+    return idx, probe_edges.queries, seq
+
+
+def _assert_seed_rows(idx, queries, pool=None):
+    """Every svs row, fused and unfused, assembles its seed as exactly
+    ``seed_n`` non-sentinel slots (padded rows: none), which is what lets
+    the probe skip the slots past ``seed_n``."""
+    for fuse in (False, True):
+        groups = batch_lib.schedule(idx, queries, pool=pool)
+        if fuse:
+            groups = batch_lib.fuse_groups(groups)
+        for key, items in groups.items():
+            if key.kind != "svs":
+                continue
+            R = np.asarray(batch_lib._assemble_svs(key, items, pool)[0])
+            real = (R != its.SENTINEL).sum(axis=1)
+            assert list(real[: len(items)]) == [it.seed_n for it in items]
+            assert not real[len(items):].any()
+
+
 def _assert_identical(results, seq):
     assert len(results) == len(seq)
     for got, want in zip(results, seq):
@@ -75,7 +107,7 @@ def _assert_identical(results, seq):
 # --------------------------------------------------------------------------
 
 @pytest.mark.parametrize("backend", ["jax", "pallas"])
-@pytest.mark.parametrize("corpus_kind", ["uniform", "skewed"])
+@pytest.mark.parametrize("corpus_kind", ["uniform", "skewed", "edges"])
 def test_fused_matches_unfused_and_sequential(request, corpus_kind, backend):
     idx, queries, seq = request.getfixturevalue(corpus_kind)
     unfused = batch_lib.execute_batch(idx, queries, backend=backend,
@@ -84,13 +116,26 @@ def test_fused_matches_unfused_and_sequential(request, corpus_kind, backend):
                                     fuse=True)
     _assert_identical(unfused, seq)
     _assert_identical(fused, seq)
+    _assert_seed_rows(idx, queries)
 
 
-@pytest.mark.parametrize("backend", ["jax", "pallas"])
-def test_fused_pool_and_pipeline_match(uniform, backend):
-    idx, queries, seq = uniform
+@pytest.mark.parametrize("corpus_kind,arena,backend", [
+    pytest.param("uniform", True, "jax", id="jax"),
+    pytest.param("uniform", True, "pallas", id="pallas"),
+    # arena=False: every group takes the pool's row-stacking path
+    pytest.param("uniform", False, "jax", id="uniform-stacked-jax"),
+    pytest.param("edges", True, "jax", id="edges-arena-jax"),
+    pytest.param("edges", False, "jax", id="edges-stacked-jax"),
+    pytest.param("edges", True, "pallas", id="edges-arena-pallas"),
+])
+def test_fused_pool_and_pipeline_match(request, monkeypatch, corpus_kind,
+                                       arena, backend):
+    idx, queries, seq = request.getfixturevalue(corpus_kind)
+    if not arena:
+        monkeypatch.setattr(batch_lib, "_arena_ok", lambda items: False)
     pool = source.ResidentPool()
     pool.warm(idx)
+    _assert_seed_rows(idx, queries, pool)
     plan = batch_lib.FusionPlan()
     _assert_identical(
         batch_lib.execute_batch(idx, queries, backend=backend, pool=pool,
@@ -108,7 +153,7 @@ def test_fused_pool_and_pipeline_match(uniform, backend):
 
 @pytest.mark.parametrize("n_shards", [1, 2, 4])
 @pytest.mark.parametrize("backend", ["jax", "pallas"])
-@pytest.mark.parametrize("corpus_kind", ["uniform", "skewed"])
+@pytest.mark.parametrize("corpus_kind", ["uniform", "skewed", "edges"])
 def test_fused_sharded_matches_sequential(request, corpus_kind, backend,
                                           n_shards):
     idx, queries, seq = request.getfixturevalue(corpus_kind)

@@ -120,24 +120,28 @@ def test_sharded_group_program_compiles(backend, topo):
     """The sharded executor's group program over a 4-chip ('data',) mesh:
     rows split across chips with no all-gather, and on the pallas backend
     one megakernel per chip (XLA cannot partition a Mosaic kernel, so the
-    program runs it per device over ``shard_map``).  The kernel mode is
-    forced compiled: this process's default backend is the CPU."""
+    program runs it per device over ``shard_map``).  The bitmap probe's
+    (JB,) chunk counts come replicated and bound a traced loop over the
+    unsharded seed axis, so they add no collective either.  The kernel
+    mode is forced compiled: this process's default backend is the CPU."""
     mesh = Mesh(np.array(topo.devices[:4]), ("data",))
 
     def shape(s, dtype, spec):
         return jax.ShapeDtypeStruct(s, dtype,
                                     sharding=NamedSharding(mesh, spec))
 
-    args =(shape((B, M), I32, P("data")),
+    args = (shape((B, M), I32, P("data")),
             shape((J, B, N), I32, P(None, "data")),
             shape((J, B), BOOL, P(None, "data")), None, None,
-            shape((JB, B, W), U32, P(None, "data")))
+            shape((JB, B, W), U32, P(None, "data")),
+            shape((JB,), I32, P()))
     prev = kernel_ops.INTERPRET
     kernel_ops.set_kernel_mode("compiled")
     try:
         text = batch_lib._svs_program.lower(
             *args, algo="gallop", backend=backend, mode="d1",
-            block_rows=ROWS, mesh=mesh).compile().as_text()
+            block_rows=ROWS, probe_chunk=batch_lib.PROBE_CHUNK,
+            mesh=mesh).compile().as_text()
     finally:
         kernel_ops.INTERPRET = prev
     assert "all-gather" not in text

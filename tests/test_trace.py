@@ -181,16 +181,21 @@ def probe_index():
 
 
 @pytest.mark.parametrize("mode,slots", [
-    # the two probing queries' four rows share one key: Jb 1 × Bp 4 × M 128
-    ("unfused", 1 * 4 * 128),
+    # the probe gathers chunks of C = 4 seed slots up to the longest real
+    # seed among rows with a bitmap: ceil(12 / 4) = 3 chunks of bitmap
+    # slot 0 (M = 128 would gather 32).  The two probing queries' four rows
+    # share one key: 3 chunks × C 4 × Bp 4
+    ("unfused", 3 * 4 * 4),
     # one family of all six rows, the fold-only query's two padding the
-    # probe: Jb 1 × Bp 6 × M 128
-    ("fused", 1 * 6 * 128),
-    # two shards of three rows each: Jb 1 × (S 2 × Bq 3) × M 128
-    ("sharded", 1 * 6 * 128),
+    # probe: 3 × 4 × Bp 6
+    ("fused", 3 * 4 * 6),
+    # two shards of three rows each, one extent over both: 3 × 4 × (S 2 ×
+    # Bq 3)
+    ("sharded", 3 * 4 * 6),
 ])
-def test_probe_slot_counters(probe_index, mode, slots):
+def test_probe_slot_counters(probe_index, monkeypatch, mode, slots):
     idx, queries = probe_index
+    monkeypatch.setattr(batch_lib, "PROBE_CHUNK", 4)
     stats = {}
     if mode == "sharded":
         out = shard_lib.execute_sharded(shard_lib.shard_index(idx, 2),
