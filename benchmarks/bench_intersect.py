@@ -44,6 +44,7 @@ def fused_ab(quick: bool = False):
 
         def fused():
             return ops.intersect_packed_fold(r, valid, pk, active,
+                                             ops.row_tiles(r),
                                              mode="d1",
                                              block_rows=pf.block_rows)
 

@@ -60,6 +60,7 @@ def fused_ab(quick: bool = False):
 
         def fused():
             return ops.intersect_packed_fold(r, valid, pk, active,
+                                             ops.row_tiles(r),
                                              mode=mode,
                                              block_rows=plist.block_rows)
 

@@ -48,7 +48,8 @@ query *batches* inside the vectorized regime:
      (J_b, B, W) — candidates never round-trip to host between terms.
      The probe runs only over each bitmap slot's real seed extent: chunks
      of ``PROBE_CHUNK`` seed slots up to the longest real seed among the
-     rows that have that slot (``probe_chunks``).
+     rows that have that slot (``probe_chunks``); the fold megakernels walk
+     only each row's real seed tiles (``seed_tiles``).
      Every step ANDs its match mask into one running validity mask over the
      *original* sorted seed buffer instead of compacting between folds:
      compaction never shrank the (static) shapes, but its cumsum+scatter
@@ -136,6 +137,7 @@ GROUP_INT_BUDGET = 1 << 25    # cap operand ints per program: B·(J·N+M+J_b·W)
 BATCH_TILED_MAX_RATIO = 4.0   # vmapped tile-merge loses early exit; see above
 PALLAS_MIN_OCCUPANCY = 0.5    # interpret-mode kernel guard; see below
 PROBE_CHUNK = 1 << 16         # seed slots per bitmap-probe step; see below
+FOLD_TILE = 128               # seed slots per fold-megakernel tile step
 
 # Interpret-mode Pallas executes every grid step on the host, so its cost
 # scales with the PADDED grid (Bp·(1+J+Jp) fused-family ceiling slots), not
@@ -157,6 +159,16 @@ PROBE_CHUNK = 1 << 16         # seed slots per bitmap-probe step; see below
 # trip count, so the compiled signatures stay those of the shapes.  At Bp =
 # 2 a chunk is ~1.5 ms of gather on a v5e against a loop step of a few µs,
 # and rounding up wastes at most one chunk per row.
+
+# The fold megakernels walk a seed row one FOLD_TILE-lane tile at a time,
+# each step a serial chain (loads, lane reductions, a scalar branch) even
+# when the tile is all SENTINEL.  A row padded to the family ceiling M is
+# mostly such tiles, so each row's walk stops after ``seed_tiles(...)[b]``
+# = ceil(seed_n / FOLD_TILE) tiles, read from scalar memory: past it every
+# slot is SENTINEL and already invalid, so the masks are those of the full
+# walk byte for byte.  The extents are an operand, not a shape, so the
+# compiled signatures stay those of the shapes; ``count_folds`` counts the
+# tiles walked against the M / FOLD_TILE per slot of a full walk.
 
 # Donating the candidate buffer lets XLA alias its pages for the output; it
 # is always freshly stacked per dispatch so nothing aliases it on the host.
@@ -422,27 +434,30 @@ def _mask_fold_scan(r, valid, folds, fold_active, intersect_fn):
     return valid
 
 
-def _row_split(fn, mesh, *fold_args):
-    """Run a megakernel per device of ``mesh`` over its slice of the batch
-    rows.  XLA cannot partition a Mosaic kernel, and group programs are
-    row-independent, so each device runs the kernel on the rows it holds:
-    ``r``/``valid`` split on axis 0, fold-stacked operands on axis 1."""
+def _row_split(fn, mesh, r, valid, fold_args, seed_tiles):
+    """Run a megakernel, ``fn(r, valid, *fold_args, seed_tiles)``, per
+    device of ``mesh`` over its slice of the batch rows.  XLA cannot
+    partition a Mosaic kernel, and group programs are row-independent, so
+    each device runs the kernel on the rows it holds: ``r``/``valid`` and
+    the row extents split on axis 0, fold-stacked operands on axis 1."""
+    args = (r, valid, *fold_args, seed_tiles)
     if mesh is None:
-        return fn
+        return fn(*args)
     from jax.sharding import PartitionSpec as P
     row, fold = P("data"), P(None, "data")
     return jax.shard_map(
         fn, mesh=mesh, out_specs=row, check_vma=False,
         in_specs=(row, row) + tuple(
-            jax.tree.map(lambda _: fold, a) for a in fold_args))
+            jax.tree.map(lambda _: fold, a) for a in fold_args) + (row,)
+    )(*args)
 
 
 @partial(jax.jit, static_argnames=("algo", "backend", "mode", "block_rows",
                                    "probe_chunk", "mesh"),
          donate_argnums=_DONATE_CANDIDATES)
 def _svs_program(r, folds, fold_active, pk, pk_active, words, probe_n,
-                 algo: str, backend: str, mode: str, block_rows: int,
-                 probe_chunk: int, mesh=None):
+                 seed_tiles, algo: str, backend: str, mode: str,
+                 block_rows: int, probe_chunk: int, mesh=None):
     """One device program per group chunk: decoded folds → packed folds →
     bitmap probes, candidates staying on device throughout.  Every stage
     computes a match mask over the original sorted seed buffer ``r`` and
@@ -453,6 +468,8 @@ def _svs_program(r, folds, fold_active, pk, pk_active, words, probe_n,
     stacked bitmap rows (or None) and ``probe_n`` its (Jb,) int32 chunk
     counts from ``probe_chunks``: bitmap slot j probes the seed slots
     [0, probe_n[j] · probe_chunk) of every row (None with ``words``).
+    ``seed_tiles`` is the (B,) int32 row extents from ``seed_tiles``: the
+    pallas megakernels walk row b's first seed_tiles[b] tiles only.
     ``r`` is donated (see module docstring).  ``mesh`` is the sharded
     executor's ('data',) mesh when the batch rows are split across devices
     (None on one device): the pallas megakernels then run per device on
@@ -464,8 +481,7 @@ def _svs_program(r, folds, fold_active, pk, pk_active, words, probe_n,
             # (grid (B, J), mask accumulated in the revisited out block)
             from repro.kernels import ops as kernel_ops
             valid = _row_split(kernel_ops.intersect_fold_batch, mesh,
-                               folds, fold_active)(r, valid, folds,
-                                                   fold_active)
+                               r, valid, (folds, fold_active), seed_tiles)
         else:
             if algo == "tiled":
                 fold_fn = partial(its.intersect_tiled_batch,
@@ -483,7 +499,7 @@ def _svs_program(r, folds, fold_active, pk, pk_active, words, probe_n,
             valid = _row_split(
                 partial(kernel_ops.intersect_packed_fold, mode=mode,
                         block_rows=block_rows),
-                mesh, pk, pk_active)(r, valid, pk, pk_active)
+                mesh, r, valid, (pk, pk_active), seed_tiles)
         else:
             valid = _mask_fold_scan(
                 r, valid, pk, pk_active,
@@ -788,7 +804,10 @@ def count_folds(stats: dict | None, items: list, backend: str, r, folds,
     runs and ``kernel_vmem_fallbacks`` those whose operands overflow the
     kernels' VMEM limit and so run the jnp reference.  ``r``/``folds``/
     ``pk`` are the launch's own operands, routed by the same
-    ``kernels.ops`` functions the program branches on."""
+    ``kernels.ops`` functions the program branches on.  Of the megakernel
+    folds, ``fold_tiles`` counts the tile steps they walk (each active
+    (j, b) slot its row's ``seed_tiles``) and ``fold_tiles_ceiling`` the
+    M / FOLD_TILE per slot a walk of the whole row would take."""
     if stats is None:
         return
     items = [it for it in items if it is not None]
@@ -798,11 +817,16 @@ def count_folds(stats: dict | None, items: list, backend: str, r, folds,
     if backend != "pallas":
         return
     from repro.kernels import ops as kernel_ops
-    kernel = dec if dec and kernel_ops.decoded_in_kernel(r, folds) else 0
-    if pk_n and kernel_ops.packed_in_kernel(r, pk, block_rows):
-        kernel += pk_n
+    dec_in = bool(dec) and kernel_ops.decoded_in_kernel(r, folds)
+    pk_in = bool(pk_n) and kernel_ops.packed_in_kernel(r, pk, block_rows)
+    kernel = dec * dec_in + pk_n * pk_in
     source._bump(stats, "kernel_folds", kernel)
     source._bump(stats, "kernel_vmem_fallbacks", dec + pk_n - kernel)
+    walks = [(len(it.folds or ()) * dec_in + len(it.psrc or ()) * pk_in,
+              _seed_tiles(it.seed_n)) for it in items]
+    source._bump(stats, "fold_tiles", sum(n * t for n, t in walks))
+    source._bump(stats, "fold_tiles_ceiling",
+                 sum(n for n, _ in walks) * (r.shape[-1] // FOLD_TILE))
 
 
 def probe_chunks(items: list, jb: int, m: int) -> tuple[np.ndarray, int]:
@@ -820,6 +844,22 @@ def probe_chunks(items: list, jb: int, m: int) -> tuple[np.ndarray, int]:
             nb = min(_n_bitmaps(it), jb)
             ext[:nb] = np.maximum(ext[:nb], it.seed_n)
     return (-(-ext // c)).astype(np.int32), c
+
+
+def _seed_tiles(n: int) -> int:
+    return -(-n // FOLD_TILE)
+
+
+def seed_tiles(items: list, bp: int) -> np.ndarray:
+    """The fold megakernels' row extents for one svs program: (Bp,) int32,
+    ceil(seed_n / FOLD_TILE) per real row, 0 for padded rows and the None
+    of sharded per-shard padding.  Every tile past a row's extent is
+    SENTINEL (already invalid), so the walk can stop there."""
+    ext = np.zeros(bp, np.int32)
+    for b, it in enumerate(items):
+        if it is not None:
+            ext[b] = _seed_tiles(it.seed_n)
+    return ext
 
 
 def count_probes(stats: dict | None, items: list, chunks: np.ndarray,
@@ -871,6 +911,7 @@ def _launch_svs_group(key: GroupKey, items: list[_Item], backend: str,
     with trace.span("dispatch"):
         return _svs_program(R, F, jnp.asarray(active), pk, pk_active, W,
                             None if W is None else jnp.asarray(chunks),
+                            jnp.asarray(seed_tiles(items, Bp)),
                             key.algo, backend, mode, rows, probe_chunk=c)
 
 

@@ -183,9 +183,10 @@ def _glue(sharded: ShardedIndex, slices: list, axis: int):
 
 
 def _put_host(sharded: ShardedIndex, arr: np.ndarray, axis: int | None):
-    """Upload one host-side operand (active masks, candidate block ids)
-    sharded along ``axis`` — each device receives only its slice; None
-    replicates it (the probe extents)."""
+    """Upload one host-side operand (active masks, candidate block ids,
+    the fold megakernels' row extents) sharded along ``axis`` — each
+    device receives only its slice; None replicates it (the probe
+    extents)."""
     if len(sharded.devices) == 1:
         return jnp.asarray(arr)
     sharding = NamedSharding(sharded.mesh, _spec(arr.ndim, axis))
@@ -265,12 +266,16 @@ def _launch_svs_sharded(sharded: ShardedIndex, key, per_shard: list,
     chunks, c = batch_lib.probe_chunks(all_items, Jb, key.m_bucket)
     batch_lib.count_probes(stats, all_items, chunks, c, S * Bq)
     probe_n = _put_host(sharded, chunks, axis=None) if Jb else None
+    # the fold megakernels' row extents split on rows like R: each device
+    # reads its own rows' extents
+    flat = _flat_items(per_shard, Bq)
+    tiles = _put_host(sharded, batch_lib.seed_tiles(flat, S * Bq), axis=0)
     with trace.span("dispatch"):
         vals, counts = batch_lib._svs_program(
-            R, F, active, pk, pk_active, W, probe_n, key.algo, backend,
-            mode, rows, probe_chunk=c,
+            R, F, active, pk, pk_active, W, probe_n, tiles, key.algo,
+            backend, mode, rows, probe_chunk=c,
             mesh=sharded.mesh if len(sharded.devices) > 1 else None)
-    return _flat_items(per_shard, Bq), vals, counts
+    return flat, vals, counts
 
 
 def _launch_bitmap_sharded(sharded: ShardedIndex, key, per_shard: list,

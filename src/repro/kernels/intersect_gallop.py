@@ -18,11 +18,13 @@ gather, so the TPU form keeps the same two steps at tile granularity
   compared ``f`` value equals it.
 
 Work per tile is the blocks its value range overlaps, and the block
-pointer only moves forward, so a whole row costs O(M/128 + N/1024) block
-comparisons plus O(log) scalar probes per tile — merge-like for dense
-pairs, galloping for skewed ones.  Lanes whose running validity is
-already 0 do not widen the window, so later folds of an SvS chain get
-cheaper as candidates die.
+pointer only moves forward, so a row costs O(T + N/1024) block
+comparisons plus O(log) scalar probes per tile, T = ceil(seed_n / 128)
+the tiles that hold the seed's real values — merge-like for dense pairs,
+galloping for skewed ones.  The walk stops at T: every tile past it is
+all SENTINEL, already invalid, so skipping it changes no mask.  Lanes
+whose running validity is already 0 do not widen the window, so later
+folds of an SvS chain get cheaper as candidates die.
 
 ``probe_tiles`` is the in-kernel routine shared by both megakernels
 (``megakernel.py``); ``gallop_tiles`` / ``gallop_tiles_batched`` /
@@ -77,13 +79,18 @@ def _first_block_reaching(f_ref, k, n_blk: int, x):
     return lo
 
 
-def probe_tiles(r_ref, f_ref, mask_ref):
-    """AND the membership of every ``r`` lane in ``f`` into ``mask_ref``.
+def probe_tiles(r_ref, f_ref, mask_ref, n_tiles):
+    """AND the membership of every ``r`` lane in ``f`` into ``mask_ref``
+    over the first ``n_tiles`` tiles of the row.
 
     r_ref: (Tr, 128) int32 sorted ascending, SENTINEL-padded; f_ref:
     (Rf, 128) int32 sorted ascending, SENTINEL-padded, Rf % 8 == 0;
     mask_ref: (Tr, 128) int32 0/1 running validity, read for liveness and
-    overwritten with ``mask & (r in f) & (r != SENTINEL)``."""
+    overwritten with ``mask & (r in f) & (r != SENTINEL)``; n_tiles: int32
+    scalar, the tiles holding the row's real values (clamped to Tr).
+    Tiles past it are left as they are, so they must be all SENTINEL with
+    a 0 mask, as every caller's are (the incoming mask is ``r !=
+    SENTINEL`` or narrower)."""
     n_blk = f_ref.shape[0] // BLOCK_ROWS
 
     def tile(t, k):
@@ -118,7 +125,8 @@ def probe_tiles(r_ref, f_ref, mask_ref):
         mask_ref[pl.ds(t, 1), :] = jnp.where(live, hit, 0)
         return k
 
-    lax.fori_loop(0, r_ref.shape[0], tile, jnp.int32(0))
+    lax.fori_loop(0, jnp.minimum(n_tiles, r_ref.shape[0]), tile,
+                  jnp.int32(0))
 
 
 # --------------------------------------------------------------------------
@@ -132,7 +140,7 @@ def gallop_tiles_batched(r, f, interpret: bool = True):
     B = r.shape[0]
     return megakernel.decoded_fold_batched(
         r, r != SENTINEL, f[None], jnp.ones((1, B), jnp.int32),
-        interpret=interpret)
+        megakernel.row_tiles(r), interpret=interpret)
 
 
 def gallop_tiles(r, f, interpret: bool = True):
@@ -154,5 +162,5 @@ def packed_gallop_batched(r, words, widths, offsets, maxes, blk_ids,
     return megakernel.packed_fold_batched(
         r, r != SENTINEL, words[None], widths[None], offsets[None],
         maxes[None], blk_ids[None], exc_pos[None], exc_add[None],
-        jnp.ones((1, B), jnp.int32), mode=mode, block_rows=block_rows,
-        interpret=interpret)
+        jnp.ones((1, B), jnp.int32), megakernel.row_tiles(r), mode=mode,
+        block_rows=block_rows, interpret=interpret)

@@ -25,13 +25,21 @@ kernel.  Inactive (j, b) slots (fused arity ceilings pad J/Jp past each
 row's real fold count) skip the step: their mask contribution is the
 identity, exactly like the host-side ``_mask_fold_scan``.
 
+Each step walks only the seed row's real tiles: a (B,) extent of
+ceil(seed_n / 128) tiles per row rides in scalar prefetch next to the
+fold activity, and ``probe_tiles`` stops there.  Past it every tile is
+SENTINEL and already invalid, so a row costs its seed's tiles, not the
+M/128 of the family ceiling it is padded to, and the answer is the same
+byte for byte.  ``row_tiles`` is the full extent, for callers that know
+no seed lengths.
+
 Layout: rows of M values are passed as (M/128, 128) tiles and masks as
 int32 0/1 (Mosaic blocks are lane-dense and carry no booleans); per-list
-metadata vectors are padded to whole 128-lane rows; fold activity rides
-in scalar prefetch.  Every kernel runs under the explicit scoped-VMEM
-limit ``VMEM_LIMIT_BYTES``; ``decoded_fold_fits`` / ``packed_fold_fits``
-say from the shapes alone whether a launch fits it, and ``kernels.ops``
-routes a launch that does not to the jnp reference.
+metadata vectors are padded to whole 128-lane rows; fold activity and the
+row extents ride in scalar prefetch.  Every kernel runs under the
+explicit scoped-VMEM limit ``VMEM_LIMIT_BYTES``; ``decoded_fold_fits`` /
+``packed_fold_fits`` say from the shapes alone whether a launch fits it,
+and ``kernels.ops`` routes a launch that does not to the jnp reference.
 """
 
 from __future__ import annotations
@@ -114,6 +122,11 @@ def _as_rows(x, fill):
     return x.reshape(x.shape[:-1] + (rows, LANES))
 
 
+def row_tiles(r):
+    """The full extent of seed rows ``r`` (B, M): M/128 tiles each."""
+    return jnp.full(r.shape[:1], r.shape[-1] // LANES, jnp.int32)
+
+
 def _i32(x):
     return (lax.bitcast_convert_type(x, jnp.int32) if x.dtype == jnp.uint32
             else x.astype(jnp.int32))
@@ -123,7 +136,7 @@ def _i32(x):
 # decoded folds: unpacked short lists, one probe per (row, fold)
 # --------------------------------------------------------------------------
 
-def _decoded_fold_kernel(act_ref, r_ref, v_ref, f_ref, out_ref):
+def _decoded_fold_kernel(act_ref, ext_ref, r_ref, v_ref, f_ref, out_ref):
     b, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
@@ -132,15 +145,18 @@ def _decoded_fold_kernel(act_ref, r_ref, v_ref, f_ref, out_ref):
 
     @pl.when(act_ref[j * pl.num_programs(0) + b] != 0)
     def _fold():
-        probe_tiles(r_ref, f_ref, out_ref)
+        probe_tiles(r_ref, f_ref, out_ref, ext_ref[b])
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def decoded_fold_batched(r, valid, folds, fold_active, interpret: bool = True):
+def decoded_fold_batched(r, valid, folds, fold_active, seed_tiles,
+                         interpret: bool = True):
     """Fused decoded SvS fold: r (B, M) sentinel-padded int32, M % 128 == 0;
-    valid (B, M) bool; folds (J, B, N) sorted, sentinel-padded; fold_active
-    (J, B).  Returns the (B, M) validity mask after ANDing all J match
-    masks — one kernel launch for the whole stack."""
+    valid (B, M) bool, False on every SENTINEL slot; folds (J, B, N)
+    sorted, sentinel-padded; fold_active (J, B); seed_tiles (B,) int32,
+    the 128-lane tiles of each row that hold real values (``row_tiles``
+    for all of them).  Returns the (B, M) validity mask after ANDing all J
+    match masks — one kernel launch for the whole stack."""
     J, B, N = folds.shape
     M = r.shape[-1]
     nf = _fold_rows(N) * LANES
@@ -148,15 +164,15 @@ def decoded_fold_batched(r, valid, folds, fold_active, interpret: bool = True):
         folds = jnp.pad(folds, ((0, 0), (0, 0), (0, nf - N)),
                         constant_values=SENTINEL)
     tm = M // LANES
-    row = lambda b, j, act: (b, 0, 0)
+    row = lambda b, j, act, ext: (b, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,                       # fold activity → SMEM
-        grid=(B, J),                                 # j innermost: the out
-        in_specs=[                                   # block is revisited
-            pl.BlockSpec((None, tm, LANES), row),
+        num_scalar_prefetch=2,                       # fold activity and
+        grid=(B, J),                                 # row extents → SMEM;
+        in_specs=[                                   # j innermost: the out
+            pl.BlockSpec((None, tm, LANES), row),    # block is revisited
             pl.BlockSpec((None, tm, LANES), row),
             pl.BlockSpec((None, None, nf // LANES, LANES),
-                         lambda b, j, act: (j, b, 0, 0)),
+                         lambda b, j, act, ext: (j, b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((None, tm, LANES), row),
     )
@@ -168,6 +184,7 @@ def decoded_fold_batched(r, valid, folds, fold_active, interpret: bool = True):
         interpret=interpret,
         name="decoded_fold",
     )(fold_active.astype(jnp.int32).reshape(-1),
+      seed_tiles.astype(jnp.int32),
       r.astype(jnp.int32).reshape(B, tm, LANES),
       valid.astype(jnp.int32).reshape(B, tm, LANES),
       folds.astype(jnp.int32).reshape(J, B, nf // LANES, LANES))
@@ -180,8 +197,8 @@ def decoded_fold_batched(r, valid, folds, fold_active, interpret: bool = True):
 
 def make_packed_fold_kernel(mode: str, block_rows: int, k_pad: int,
                             n_cand: int, n_exc: int):
-    def kernel(act_ref, r_ref, v_ref, w_ref, wid_ref, off_ref, max_ref,
-               blk_ref, ep_ref, ea_ref, out_ref, win_ref):
+    def kernel(act_ref, ext_ref, r_ref, v_ref, w_ref, wid_ref, off_ref,
+               max_ref, blk_ref, ep_ref, ea_ref, out_ref, win_ref):
         b, j = pl.program_id(0), pl.program_id(1)
 
         @pl.when(j == 0)
@@ -194,35 +211,36 @@ def make_packed_fold_kernel(mode: str, block_rows: int, k_pad: int,
                 w_ref, wid_ref, off_ref, max_ref, blk_ref, ep_ref, ea_ref,
                 win_ref, mode=mode, block_rows=block_rows, k_pad=k_pad,
                 n_cand=n_cand, n_exc=n_exc)
-            probe_tiles(r_ref, win_ref, out_ref)
+            probe_tiles(r_ref, win_ref, out_ref, ext_ref[b])
     return kernel
 
 
 @partial(jax.jit, static_argnames=("mode", "block_rows", "interpret"))
 def packed_fold_batched(r, valid, words, widths, offsets, maxes, blk_ids,
-                        exc_pos, exc_add, active, mode: str, block_rows: int,
-                        interpret: bool = True):
+                        exc_pos, exc_add, active, seed_tiles, mode: str,
+                        block_rows: int, interpret: bool = True):
     """Fused packed SvS fold.  r (B, M) sentinel-padded int32; valid (B, M)
-    bool; words (Jp, B, Tp, 128) uint32; widths/offsets/maxes (Jp, B, Kp);
-    blk_ids (Jp, B, C) ascending, padded with Kp; exc_pos / exc_add
-    (Jp, B, E) FastPFOR patches (-1-padded); active (Jp, B).  Returns the
-    (B, M) validity mask after folding all Jp packed lists — one kernel
-    launch, decode scratch only, no decoded array in HBM."""
+    bool, False on every SENTINEL slot; words (Jp, B, Tp, 128) uint32;
+    widths/offsets/maxes (Jp, B, Kp); blk_ids (Jp, B, C) ascending, padded
+    with Kp; exc_pos / exc_add (Jp, B, E) FastPFOR patches (-1-padded);
+    active (Jp, B); seed_tiles (B,) int32 as in ``decoded_fold_batched``.
+    Returns the (B, M) validity mask after folding all Jp packed lists —
+    one kernel launch, decode scratch only, no decoded array in HBM."""
     Jp, B, Tp, _ = words.shape
     M = r.shape[-1]
     Kp = widths.shape[-1]
     C = blk_ids.shape[-1]
     E = exc_pos.shape[-1]
     tm = M // LANES
-    row = lambda b, j, act: (b, 0, 0)
-    jb = lambda b, j, act: (j, b, 0, 0)
+    row = lambda b, j, act, ext: (b, 0, 0)
+    jb = lambda b, j, act, ext: (j, b, 0, 0)
     meta = [_as_rows(_i32(x), 0) for x in (widths, offsets, maxes)]
     blk = _as_rows(_i32(blk_ids), Kp)
     ep = _as_rows(_i32(exc_pos), -1)
     ea = _as_rows(_i32(exc_add), 0)
     operands = [words.astype(jnp.uint32), *meta, blk, ep, ea]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,                       # fold activity → SMEM
+        num_scalar_prefetch=2,                       # activity, extents
         grid=(B, Jp),                                # j innermost: the out
         in_specs=[pl.BlockSpec((None, tm, LANES), row)] * 2   # revisited
         + [pl.BlockSpec((None, None) + x.shape[2:], jb) for x in operands],
@@ -236,7 +254,7 @@ def packed_fold_batched(r, valid, words, widths, offsets, maxes, blk_ids,
         compiler_params=compiler_params(),
         interpret=interpret,
         name="packed_fold",
-    )(active.astype(jnp.int32).reshape(-1),
+    )(active.astype(jnp.int32).reshape(-1), seed_tiles.astype(jnp.int32),
       r.astype(jnp.int32).reshape(B, tm, LANES),
       valid.astype(jnp.int32).reshape(B, tm, LANES), *operands)
     return out.reshape(B, M) != 0

@@ -86,6 +86,7 @@ ROWS = _bitunpack.ROWS
 LANES = _bitunpack.LANES
 decoded_fold_fits = _megakernel.decoded_fold_fits
 packed_fold_fits = _megakernel.packed_fold_fits
+row_tiles = _megakernel.row_tiles
 
 
 def decoded_in_kernel(r, f) -> bool:
@@ -235,10 +236,12 @@ def _fold_scan(r, valid, stack, active, intersect_fn):
     return valid
 
 
-def intersect_fold_batch(r, valid, folds, fold_active):
+def intersect_fold_batch(r, valid, folds, fold_active, seed_tiles):
     """Fused decoded SvS fold: ONE kernel launch ANDs the match masks of the
     whole (J, B, N) fold stack into ``valid`` (grid (B, J), the output mask
-    block revisited across j).  The jnp reference when a fold row does not
+    block revisited across j), each row walked over its first
+    ``seed_tiles[b]`` 128-lane tiles only (``valid`` False past them).
+    The jnp reference, which walks every slot, when a fold row does not
     fit VMEM."""
     if folds.shape[0] == 0:
         return valid
@@ -247,16 +250,18 @@ def intersect_fold_batch(r, valid, folds, fold_active):
         return _fold_scan(r, valid, folds, fold_active,
                           core_intersect.intersect_gallop_batch)
     return _megakernel.decoded_fold_batched(r, valid, folds, fold_active,
+                                            seed_tiles,
                                             interpret=_interpret())
 
 
-def intersect_packed_fold(r, valid, pk, pk_active, mode: str,
+def intersect_packed_fold(r, valid, pk, pk_active, seed_tiles, mode: str,
                           block_rows: int):
     """Fused packed SvS fold: ONE kernel launch decodes each (j, b) slot's
     candidate blocks in VMEM scratch and ANDs the match masks of the whole
     (Jp, B, ...) packed stack into ``valid`` — no materialized decoded
     array (DESIGN.md §2.12).  ``pk`` is the stacked operand tuple in
-    ``batch._compose_pk`` order.  The jnp reference when one slot's
+    ``batch._compose_pk`` order; ``seed_tiles`` as in
+    ``intersect_fold_batch``.  The jnp reference when one slot's
     compressed list and decode window do not fit VMEM."""
     words, widths, offsets, maxes, blk_ids, exc_pos, exc_add = pk
     if words.shape[0] == 0:
@@ -269,4 +274,5 @@ def intersect_packed_fold(r, valid, pk, pk_active, mode: str,
                 rr, *op, mode=mode, block_rows=block_rows))
     return _megakernel.packed_fold_batched(
         r, valid, words, widths, offsets, maxes, blk_ids, exc_pos, exc_add,
-        pk_active, mode=mode, block_rows=block_rows, interpret=_interpret())
+        pk_active, seed_tiles, mode=mode, block_rows=block_rows,
+        interpret=_interpret())

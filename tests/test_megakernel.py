@@ -8,8 +8,11 @@ differential against the staged reference — per-fold
 as ``batch._mask_fold_scan`` does — plus the scalar ``intersect_ref``
 oracle where the payload permits.  Coverage: delta modes d1–dv, FastPFOR
 exception patching, sentinel padding, incoming-valid masking, inactive
-fold slots, empty/single-block edges, and fused-family ceiling shapes
-(k/t/c pads and B/Jp arities raised far past the payload).  Interpret
+fold slots, empty/single-block edges, fused-family ceiling shapes
+(k/t/c pads and B/Jp arities raised far past the payload), and the row
+extents: seeds of 0, 1, 127, 128, 129 and M slots in one launch walk
+only their own 128-lane tiles, byte-identical to the full walk, while a
+walk one tile short is caught.  Interpret
 mode everywhere; the same parametrized bodies also run compiled when a
 TPU backend is present (``_COMPILED``).
 
@@ -104,6 +107,61 @@ def _stack_payloads(grid, r_rows, *, M=256, k_pad=None, t_pad=None,
     return jnp.asarray(Rnp), pk, jnp.asarray(active)
 
 
+def _tiles(R):
+    """Each seed row's real extent in 128-lane tiles, as
+    ``batch.seed_tiles`` gives it from ``seed_n``."""
+    n = (np.asarray(R) != its.SENTINEL).sum(axis=1)
+    return jnp.asarray(-(-n // 128), jnp.int32)
+
+
+# Seed lengths of one ragged launch, M the row's slots: empty, one slot,
+# either side of a 128-lane tile edge, and the whole row.
+def _ragged(M):
+    return (0, 1, 127, 128, 129, M)
+
+
+def _seed_rows(rng, lengths, M, members=None, universe=1 << 20):
+    """Sorted rows of the given lengths, SENTINEL-padded to M.  About half
+    of a row's values come from ``members`` (when given), the rest are
+    random below ``universe``; a row's last value is ``universe + b``, in
+    no fold: a planted miss that survives only where a walk skips it.
+    ``valid`` has holes (values ≡ 0 mod 3 killed), is False on every
+    SENTINEL and True on every planted miss."""
+    R = np.full((len(lengths), M), its.SENTINEL, np.int32)
+    for b, n in enumerate(lengths):
+        if not n:
+            continue
+        k = (n - 1) // 2 if members is not None else 0
+        mem = rng.choice(members, k, replace=False) if k else []
+        rest = np.setdiff1d(rng.choice(universe, 2 * n, replace=False), mem)
+        body = np.concatenate([mem, rng.choice(rest, n - 1 - k,
+                                               replace=False)])
+        R[b, :n] = np.append(np.sort(body), universe + b)
+    valid = (R != its.SENTINEL) & (R % 3 != 0)
+    for b, n in enumerate(lengths):
+        if n:
+            valid[b, n - 1] = True
+    return jnp.asarray(R), jnp.asarray(valid)
+
+
+def _assert_extents(launch, R, ref, short):
+    """The walk over each row's real tiles, and the full walk, both equal
+    ``ref`` byte for byte.  With ``short`` set, the row of 129 seed slots
+    walks one tile less: its planted miss at slot 128 is left unprobed
+    and survives, the one slot where the result differs from ``ref``."""
+    ref = np.asarray(ref)
+    tiles = _tiles(R)
+    full = np.asarray(launch(megakernel.row_tiles(R)))
+    assert np.array_equal(full, ref)
+    if not short:
+        assert np.array_equal(np.asarray(launch(tiles)), ref)
+        return
+    b = int(np.flatnonzero(np.asarray(tiles) == 2)[0])
+    got = np.asarray(launch(tiles.at[b].add(-1)))
+    assert list(zip(*np.nonzero(got != ref))) == [(b, 128)]
+    assert got[b, 128] and not ref[b, 128]
+
+
 def _staged_packed_fold(R, valid, pk, active, mode, block_rows):
     """Reference: per-fold core intersect_packed_batch masks ANDed as
     ``batch._mask_fold_scan`` does — the staged packed path."""
@@ -134,7 +192,7 @@ def test_packed_fold_matches_staged_all_modes(mode, rng):
     R, pk, active = _stack_payloads(grid, r_rows)
     valid = R != its.SENTINEL
     rows = grid[0][0].block_rows
-    got = kernel_ops.intersect_packed_fold(R, valid, pk, active,
+    got = kernel_ops.intersect_packed_fold(R, valid, pk, active, _tiles(R),
                                            mode=mode, block_rows=rows)
     ref = _staged_packed_fold(R, valid, pk, active, mode, rows)
     assert np.array_equal(np.asarray(got), np.asarray(ref))
@@ -158,25 +216,43 @@ def test_packed_fold_fastpfor_exceptions(rng):
     R, pk, active = _stack_payloads(grid, r_rows)
     valid = R != its.SENTINEL
     rows = grid[0][0].block_rows
-    got = kernel_ops.intersect_packed_fold(R, valid, pk, active,
+    got = kernel_ops.intersect_packed_fold(R, valid, pk, active, _tiles(R),
                                            mode="d1", block_rows=rows)
     ref = _staged_packed_fold(R, valid, pk, active, "d1", rows)
     assert np.array_equal(np.asarray(got), np.asarray(ref))
 
 
-def test_packed_fold_sentinel_padding_and_incoming_valid(rng):
+@pytest.mark.parametrize("lengths,M,short", [
+    # one short row in a long padded one (a heavy sentinel tail)
+    pytest.param((80,), 1024, False, id="one-row"),
+    # rows of 0, 1, 127, 128, 129 and M seed slots in one launch
+    pytest.param(_ragged(512), 512, False, id="ragged"),
+    # the control: the 129-slot row walks one tile short
+    pytest.param(_ragged(512), 512, True, id="one-tile-short"),
+])
+def test_packed_fold_sentinel_padding_and_incoming_valid(rng, lengths, M,
+                                                         short):
     """Sentinel-padded seed slots never match; rows the incoming validity
-    mask already killed stay dead (the megakernel ANDs, never revives)."""
-    r, f = _pair(rng, 80, 60000)
-    pf = bitpack.encode(f, mode="d1")
-    R, pk, active = _stack_payloads([[pf]], [r], M=1024)  # heavy tail
-    valid = (R != its.SENTINEL) & (R % 2 == 0)            # pre-killed odds
-    got = np.asarray(kernel_ops.intersect_packed_fold(
-        R, valid, pk, active, mode="d1", block_rows=pf.block_rows))
-    assert not got[0, len(r):].any()
-    assert not got[0][np.asarray(R)[0] % 2 == 1].any()
-    ref = _staged_packed_fold(R, valid, pk, active, "d1", pf.block_rows)
-    assert np.array_equal(got, np.asarray(ref))
+    mask already killed stay dead (the megakernel ANDs, never revives).
+    Each row walks only its real tiles, with inactive (j, b) slots, and
+    matches the staged reference and the full walk byte for byte."""
+    _, f0 = _pair(rng, 80, 60000, universe=2**20)
+    _, f1 = _pair(rng, 80, 40000, universe=2**20)
+    R, valid = _seed_rows(rng, lengths, M, members=np.union1d(f0, f1))
+    pf = [bitpack.encode(f, mode="d1") for f in (f0, f1)]
+    B = len(lengths)
+    grid = [[pf[0]] * B, [pf[1] if b % 2 else None for b in range(B)]]
+    rows = [np.asarray(R)[b, :n] for b, n in enumerate(lengths)]
+    _, pk, active = _stack_payloads(grid, rows, M=M)
+    ref = _staged_packed_fold(R, valid, pk, active, "d1", pf[0].block_rows)
+    assert not np.asarray(ref)[~np.asarray(valid)].any()
+
+    def launch(tiles):
+        return kernel_ops.intersect_packed_fold(
+            R, valid, pk, active, tiles, mode="d1",
+            block_rows=pf[0].block_rows)
+
+    _assert_extents(launch, R, ref, short)
 
 
 def test_packed_fold_inactive_and_empty_edges(rng):
@@ -197,7 +273,7 @@ def test_packed_fold_inactive_and_empty_edges(rng):
     assert not np.asarray(active)[1].any()
     valid = R != its.SENTINEL
     brows = pf.block_rows
-    got = kernel_ops.intersect_packed_fold(R, valid, pk, active,
+    got = kernel_ops.intersect_packed_fold(R, valid, pk, active, _tiles(R),
                                            mode="d1", block_rows=brows)
     ref = _staged_packed_fold(R, valid, pk, active, "d1", brows)
     assert np.array_equal(np.asarray(got), np.asarray(ref))
@@ -218,7 +294,8 @@ def test_packed_fold_family_ceiling_shapes(rng):
     rows = pf.block_rows
     R1, pk1, a1 = _stack_payloads([[pf]], [r])
     tight = kernel_ops.intersect_packed_fold(
-        R1, R1 != its.SENTINEL, pk1, a1, mode="dm", block_rows=rows)
+        R1, R1 != its.SENTINEL, pk1, a1, _tiles(R1), mode="dm",
+        block_rows=rows)
     k_pad = 4 * its.pow2_bucket(pf.widths.shape[0], floor=1)
     t_pad = 2 * its.pow2_bucket(int(pf.flat_words.shape[0]), floor=1)
     grid = [[pf, None, None, None], [None] * 4, [None] * 4, [None] * 4]
@@ -227,7 +304,8 @@ def test_packed_fold_family_ceiling_shapes(rng):
         bp=4)
     assert pk4[0].shape[:2] == (4, 4)
     got = kernel_ops.intersect_packed_fold(
-        R4, R4 != its.SENTINEL, pk4, a4, mode="dm", block_rows=rows)
+        R4, R4 != its.SENTINEL, pk4, a4, _tiles(R4), mode="dm",
+        block_rows=rows)
     assert np.array_equal(np.asarray(got)[0], np.asarray(tight)[0])
     assert not np.asarray(got)[1:].any() or np.array_equal(
         np.asarray(got)[1:], np.asarray(R4[1:] != its.SENTINEL))
@@ -237,25 +315,36 @@ def test_packed_fold_family_ceiling_shapes(rng):
 # decoded-fold megakernel
 # --------------------------------------------------------------------------
 
-def test_decoded_fold_matches_scan(rng):
-    B, M, N, J = 4, 256, 1024, 3
-    r = np.sort(rng.choice(1 << 20, (B, M), replace=False),
-                axis=1).astype(np.int32)
-    folds = np.sort(rng.choice(1 << 20, (J, B, N)), axis=-1).astype(np.int32)
-    folds[0, :, :50] = r[:, 10:60]
-    folds = np.sort(folds, axis=-1)
-    act = rng.random((J, B)) < 0.7
-    act[0, 0] = act[1, 0] = True
-    valid = r % 3 != 0
-    got = kernel_ops.intersect_fold_batch(
-        jnp.asarray(r), jnp.asarray(valid), jnp.asarray(folds),
-        jnp.asarray(act))
-    ref = jnp.asarray(valid)
+@pytest.mark.parametrize("lengths,M,short", [
+    # four whole rows: no SENTINEL, every tile real
+    pytest.param((256,) * 4, 256, False, id="full"),
+    # rows of 0, 1, 127, 128, 129 and M seed slots in one launch
+    pytest.param(_ragged(512), 512, False, id="ragged"),
+    # the control: the 129-slot row walks one tile short
+    pytest.param(_ragged(512), 512, True, id="one-tile-short"),
+])
+def test_decoded_fold_matches_scan(rng, lengths, M, short):
+    """Each row walks only its real tiles, with inactive (j, b) slots and
+    an incoming mask with holes, and matches ``_fold_scan`` (per-fold
+    gallop masks ANDed) and the full walk byte for byte."""
+    B, N, J = len(lengths), 1024, 3
+    r, valid = _seed_rows(rng, lengths, M)
+    folds = np.empty((J, B, N), np.int32)
     for j in range(J):
-        hit = its.intersect_gallop_batch(jnp.asarray(r),
-                                         jnp.asarray(folds[j]))
-        ref = ref & jnp.where(jnp.asarray(act[j])[:, None], hit, True)
-    assert np.array_equal(np.asarray(got), np.asarray(ref))
+        for b, n in enumerate(lengths):
+            body = np.asarray(r)[b, : max(n - 1, 0)]
+            hits = body[rng.random(body.size) < 0.5]    # the planted miss
+            rest = np.setdiff1d(rng.choice(1 << 20, N, replace=False), hits)
+            folds[j, b] = np.sort(np.concatenate([hits, rest])[:N])
+    act = rng.random((J, B)) < 0.7
+    act[0] = True                                  # every row folds once
+    folds, act = jnp.asarray(folds), jnp.asarray(act)
+    ref = kernel_ops._fold_scan(r, valid, folds, act,
+                                its.intersect_gallop_batch)
+    _assert_extents(
+        lambda tiles: kernel_ops.intersect_fold_batch(r, valid, folds, act,
+                                                      tiles),
+        r, ref, short)
 
 
 def test_decoded_fold_empty_stack_is_identity(rng):
@@ -264,7 +353,7 @@ def test_decoded_fold_empty_stack_is_identity(rng):
     valid = r % 2 == 0
     got = kernel_ops.intersect_fold_batch(
         r, valid, jnp.zeros((0, 2, 128), jnp.int32),
-        jnp.zeros((0, 2), bool))
+        jnp.zeros((0, 2), bool), megakernel.row_tiles(r))
     assert np.array_equal(np.asarray(got), np.asarray(valid))
 
 
@@ -278,8 +367,8 @@ def test_compiled_mode_matches_interpret(rng):
     out = {}
     for interp in (True, False):
         out[interp] = np.asarray(megakernel.packed_fold_batched(
-            R, valid, *pk, active, mode="d1", block_rows=pf.block_rows,
-            interpret=interp))
+            R, valid, *pk, active, _tiles(R), mode="d1",
+            block_rows=pf.block_rows, interpret=interp))
     assert np.array_equal(out[True], out[False])
 
 
@@ -302,6 +391,77 @@ def test_engine_pallas_megakernel_matches_sequential(fuse):
                                   fuse=fuse)
     for a, b in zip(got, seq):
         assert a.count == b.count and np.array_equal(a.docs, b.docs)
+
+
+def _fold_extent_run(corpus, mode):
+    """Serve ``corpus`` (``conftest.make_probe_edge_corpus``) through the
+    pallas megakernels in ``mode``; returns (results, sequential, stats)."""
+    from repro.index import shard as shard_lib
+    idx = builder.build(corpus.postings, corpus.n_docs,
+                        codec_name="fastpfor-d1", B=8, n_parts=2)
+    seq = [engine.query(idx, q) for q in corpus.queries]
+    stats: dict = {}
+    if mode.startswith("sharded"):
+        sharded = shard_lib.shard_index(idx, 2)
+        stats["devices"] = len(sharded.devices)
+        got = shard_lib.execute_sharded(sharded, corpus.queries,
+                                        batch_size=6, backend="pallas",
+                                        stats=stats)
+    elif mode == "pool-arena":
+        pool = source.ResidentPool()
+        pool.warm(idx)
+        got = batch_lib.execute_batch(idx, corpus.queries, backend="pallas",
+                                      pool=pool, stats=stats)
+    else:
+        got = batch_lib.execute_batch(idx, corpus.queries, backend="pallas",
+                                      fuse=mode == "fused", stats=stats)
+    return got, seq, stats
+
+
+@pytest.mark.parametrize("mode", ["unfused", "fused", "pool-arena",
+                                  "sharded"])
+def test_engine_fold_extents_match_sequential(probe_edge_corpus,
+                                              monkeypatch, mode):
+    """Seeds of 127, 128 and 129 postings, either side of a 128-lane tile
+    edge (``probe_edge_corpus`` terms 8, 11, 12), folded by the megakernels
+    over their own tiles only: byte-identical to the sequential engine,
+    unfused, fused, from the pool's arenas and sharded over 2 of 4 host
+    devices (a subprocess: the row extents then split across devices with
+    the seed rows).  A skipped live tile would keep a seed's last doc,
+    which is in no fold, as a phantom hit."""
+    if mode == "sharded":
+        import subprocess
+        import sys
+        tests = os.path.dirname(os.path.abspath(__file__))
+        code = ("import sys\n"
+                f"sys.path.insert(0, {tests!r})\n"
+                "import conftest, test_megakernel as t\n"
+                "t.batch_lib.PALLAS_MIN_OCCUPANCY = 0.0\n"
+                "got, seq, st = t._fold_extent_run("
+                "conftest.make_probe_edge_corpus(), 'sharded')\n"
+                "assert st['devices'] == 2, st['devices']\n"
+                "t._assert_fold_extents(got, seq, st, 'sharded')\n")
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   XLA_FLAGS="--xla_force_host_platform_device_count=4",
+                   PYTHONPATH=os.path.join(os.path.dirname(tests), "src"))
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=600)
+        assert out.returncode == 0, out.stderr[-3000:]
+        return
+    monkeypatch.setattr(batch_lib, "PALLAS_MIN_OCCUPANCY", 0.0)
+    _assert_fold_extents(*_fold_extent_run(probe_edge_corpus, mode), mode)
+
+
+def _assert_fold_extents(got, seq, stats, mode):
+    assert len(got) == len(seq)
+    for a, b in zip(got, seq):
+        assert a.count == b.count and np.array_equal(a.docs, b.docs)
+    assert stats["kernel_folds"] == stats["folds"] > 0
+    # fusion pads every seed row to the family's longest (256 slots): the
+    # walk then stops short of it; unfused, M is each seed's own bucket
+    assert 0 < stats["fold_tiles"] <= stats["fold_tiles_ceiling"]
+    if mode != "unfused":
+        assert stats["fold_tiles"] < stats["fold_tiles_ceiling"]
 
 
 # --------------------------------------------------------------------------

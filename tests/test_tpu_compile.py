@@ -29,10 +29,11 @@ from repro.kernels import svb_decode
 
 # largest fused svs signature of the full-size (50M-doc) warm-up on a v5e:
 # seed rows M, fold rows N, J decoded folds, JB bitmaps of W words, as
-# chip_smoke.py prints them, with Bp <= 2 there.  No
-# packed family forms at that size; the packed shapes are a mid-size list
-# (4096 word rows, 512 blocks, a 512-block candidate window).
-B, M, N, J, JB, W = 8, 1 << 19, 1 << 21, 4, 4, 781250
+# chip_smoke.py prints them and the device trace names them, with Bp <= 2
+# there.  No packed family forms at that size; the packed shapes are a
+# mid-size list (4096 word rows, 512 blocks, a 512-block candidate window).
+# Both megakernels take the (B,) row extents in scalar prefetch.
+B, M, N, J, JB, W = 8, 1 << 20, 1 << 21, 4, 4, 781250
 JP, T_PAD, K_PAD, C_PAD, E_PAD, ROWS = 2, 4096, 512, 512, 8192, 32
 
 
@@ -75,9 +76,10 @@ def _packed_shapes(lead):
 
 
 def test_decoded_fold_compiles(one_chip):
-    _compile(lambda r, v, f, a: megakernel.decoded_fold_batched(
-        r, v, f, a, interpret=False), one_chip,
-        ((B, M), I32), ((B, M), BOOL), ((J, B, N), I32), ((J, B), BOOL))
+    _compile(lambda r, v, f, a, t: megakernel.decoded_fold_batched(
+        r, v, f, a, t, interpret=False), one_chip,
+        ((B, M), I32), ((B, M), BOOL), ((J, B, N), I32), ((J, B), BOOL),
+        ((B,), I32))
 
 
 @pytest.mark.parametrize("mode", ["d1", "dm", "d4"])
@@ -85,7 +87,7 @@ def test_packed_fold_compiles(mode, one_chip):
     _compile(lambda r, v, *pk: megakernel.packed_fold_batched(
         r, v, *pk, mode=mode, block_rows=ROWS, interpret=False), one_chip,
         ((B, M), I32), ((B, M), BOOL), *_packed_shapes((JP, B)),
-        ((JP, B), BOOL))
+        ((JP, B), BOOL), ((B,), I32))
 
 
 def test_gallop_tiles_batched_compiles(one_chip):
@@ -122,8 +124,10 @@ def test_sharded_group_program_compiles(backend, topo):
     one megakernel per chip (XLA cannot partition a Mosaic kernel, so the
     program runs it per device over ``shard_map``).  The bitmap probe's
     (JB,) chunk counts come replicated and bound a traced loop over the
-    unsharded seed axis, so they add no collective either.  The kernel
-    mode is forced compiled: this process's default backend is the CPU."""
+    unsharded seed axis, so they add no collective either; the (B,) row
+    extents of the fold megakernels split on rows like the seeds.  The
+    kernel mode is forced compiled: this process's default backend is the
+    CPU."""
     mesh = Mesh(np.array(topo.devices[:4]), ("data",))
 
     def shape(s, dtype, spec):
@@ -134,7 +138,7 @@ def test_sharded_group_program_compiles(backend, topo):
             shape((J, B, N), I32, P(None, "data")),
             shape((J, B), BOOL, P(None, "data")), None, None,
             shape((JB, B, W), U32, P(None, "data")),
-            shape((JB,), I32, P()))
+            shape((JB,), I32, P()), shape((B,), I32, P("data")))
     prev = kernel_ops.INTERPRET
     kernel_ops.set_kernel_mode("compiled")
     try:

@@ -210,6 +210,43 @@ def test_probe_slot_counters(probe_index, monkeypatch, mode, slots):
     assert stats["probe_slots_useful"] == 34
 
 
+@pytest.mark.parametrize("mode,ceiling", [
+    # the fold-only query's seed (term 1: 10 + 7 docs) folds term 3 in
+    # each part: two active slots of one tile each; unfused, M = 128 is
+    # the seed's own bucket, one tile a slot
+    ("unfused", 2 * 1),
+    # the plan pins the family's M at 512: a full walk is 4 tiles a slot,
+    # the real one still 1
+    ("fused", 2 * 4),
+    # two shards of three rows, the same family ceiling
+    ("sharded", 2 * 4),
+])
+def test_fold_tile_counters(probe_index, monkeypatch, mode, ceiling):
+    """``fold_tiles`` counts the tile steps the fold megakernels walk, each
+    active (j, b) slot its row's ``seed_tiles``; ``fold_tiles_ceiling``
+    the same slots at M / 128 tiles, a walk of the whole padded row.
+    Rows without a fold (the probing queries) and padded rows add
+    nothing."""
+    idx, queries = probe_index
+    monkeypatch.setattr(batch_lib, "PALLAS_MIN_OCCUPANCY", 0.0)
+    plan = batch_lib.FusionPlan()
+    plan.raised(("svs", None), (512, 0, 0, 0, 0))
+    stats = {}
+    if mode == "sharded":
+        out = shard_lib.execute_sharded(shard_lib.shard_index(idx, 2),
+                                        queries, batch_size=3,
+                                        backend="pallas", plan=plan,
+                                        stats=stats)
+    else:
+        out = batch_lib.execute_batch(idx, queries, backend="pallas",
+                                      fuse=mode == "fused", plan=plan,
+                                      stats=stats)
+    _assert_identical(out, [engine.query(idx, q) for q in queries])
+    assert stats["kernel_folds"] == 2
+    assert stats["fold_tiles"] == 2
+    assert stats["fold_tiles_ceiling"] == ceiling
+
+
 @pytest.mark.parametrize("depth", [1, 2])
 def test_d2h_bytes(uniform, monkeypatch, fast_switch, depth):
     """``d2h_bytes`` is the bytes of every collected chunk's vals and
